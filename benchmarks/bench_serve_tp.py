@@ -129,7 +129,10 @@ def run_tp8() -> dict:
               .replace("__MAX_SEQ__", str(MAX_SEQ))
               .replace("__PAGE_SIZE__", str(PAGE_SIZE))
               .replace("__CHUNK__", str(PREFILL_CHUNK)))
+    # 8 virtual host devices is a CPU check by design; the child stays off
+    # any accelerator, which the parent (a JAX user already) holds
     env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=600)
     if out.returncode != 0:

@@ -22,6 +22,9 @@ runtime pipeline enforces dynamically:
   * ``tuner.measure`` — the padded block-volume cap a lowering would trip
     (reported as a warning: the measurement layer owns that failure mode
     and its ValueError capture is load-bearing for the tuning DB).
+  * the TPU compiler — :func:`verify_kernel_blocks` checks the blocks of
+    the kernel a candidate lowers to against the (8, 128) tiling rule and
+    the scoped VMEM limit, for measurement on the chip.
 
 Pure ``core``-level module: no jax, importable from the tuner's measurement
 hot path at zero cost.
@@ -99,6 +102,19 @@ R_BLOCK_VOLUME = rule(
     "legality/block-volume",
     "padded tile volume exceeds the measurement layer's max_block_elems "
     "cap: lowering this candidate would be refused")
+R_BLOCK_TILING = rule(
+    "legality/block-tiling",
+    "a kernel block's two minor dims are neither multiples of the TPU tile "
+    "(8, 128) nor the whole array dims: the chip's compiler refuses it")
+R_BLOCK_VMEM = rule(
+    "legality/block-vmem",
+    "the kernel's double-buffered blocks plus its f32 scratch exceed the "
+    "compiler's scoped VMEM limit: the chip's compiler refuses it")
+
+TPU_TILE = (8, 128)
+# Mosaic's default scoped VMEM limit on v5e: a v5e compile of the GEMM
+# kernel with 13 MiB of blocks passes and one with 18 MiB runs out of VMEM
+TPU_SCOPED_VMEM_BYTES = 16 << 20
 
 _POW2_KNOBS = ("pe_rows", "pe_cols", "pe_depth")
 
@@ -269,6 +285,72 @@ def verify_candidate(workload: TensorExpr, schedule: Schedule, hw: HWConfig,
             out.append(Finding("warning", R_BLOCK_VOLUME, site,
                                f"padded tile volume {vol} exceeds "
                                f"max_block_elems={max_block_elems}"))
+    return out
+
+
+def _cdiv_up(n: int, b: int) -> int:
+    return -(-n // b) * b
+
+
+def _kernel_buffers(op: str, shape, blocks: dict[str, int]):
+    """-> ([(block, array), ...] for every operand and the output, f32
+    scratch elements), as the ``repro.kernels`` wrappers clamp blocks to the
+    problem and pad arrays to whole blocks."""
+    if op == "gemm":
+        m, n, k = shape
+        bm, bn, bk = (min(blocks[b], d) for b, d in
+                      (("bm", m), ("bn", n), ("bk", k)))
+        mp, np_, kp = _cdiv_up(m, bm), _cdiv_up(n, bn), _cdiv_up(k, bk)
+        return ([((bm, bk), (mp, kp)), ((bk, bn), (kp, np_)),
+                 ((bm, bn), (mp, np_))], bm * bn)
+    if op == "gemv":
+        m, k = shape
+        bm, bk = min(blocks["bm"], m), min(blocks["bk"], k)
+        mp, kp = _cdiv_up(m, bm), _cdiv_up(k, bk)
+        return ([((bm, bk), (mp, kp)), ((1, bk), (1, kp)),
+                 ((bm, 1), (mp, 1))], bm)
+    if op == "dot":
+        (k,) = shape
+        bk = min(blocks["bk"], k)
+        kp = _cdiv_up(k, bk)
+        return [((1, bk), (1, kp))] * 2 + [((1, 1), (1, 1))], 1
+    if op == "conv2d":
+        kk, c, x, y, r, s = shape
+        bk = min(blocks["bk"], kk)
+        flat = (x + r - 1) * (y + s - 1) + s - 1   # row-flattened input
+        n = x * (y + s - 1)
+        return ([((c, flat), (c, flat)), ((r * s, bk, c), (r * s, kk, c)),
+                 ((bk, n), (kk, n))], bk * n)
+    raise ValueError(f"unknown kernel op {op!r}")
+
+
+def verify_kernel_blocks(op: str, shape, blocks: dict[str, int],
+                         itemsize: int, *,
+                         site: str | None = None) -> list[Finding]:
+    """The rules the TPU's compiler applies to the kernel a candidate lowers
+    to (``tuner.measure.kernel_point``).  The Pallas interpreter checks
+    neither, so they gate only measurement on the chip."""
+    site = site or f"{op}{tuple(shape)}{sorted(blocks.items())}"
+    buffers, scratch = _kernel_buffers(op, shape, blocks)
+    out: list[Finding] = []
+    for blk, arr in buffers:
+        for b, a, tile in zip(blk[-2:], arr[-2:], TPU_TILE):
+            if b % tile and b != a:
+                out.append(Finding("error", R_BLOCK_TILING, site,
+                                   f"block {blk} of array {arr}: {b} is "
+                                   f"neither a multiple of {tile} nor {a}"))
+                break
+    vmem = 0
+    for blk, _ in buffers:
+        n = 1
+        for d in blk:
+            n *= d
+        vmem += 2 * n * itemsize
+    vmem += scratch * ACC_BYTES
+    if vmem > TPU_SCOPED_VMEM_BYTES:
+        out.append(Finding("error", R_BLOCK_VMEM, site,
+                           f"{vmem}B of blocks and scratch > "
+                           f"{TPU_SCOPED_VMEM_BYTES}B scoped VMEM"))
     return out
 
 

@@ -413,6 +413,15 @@ def _codesign_body(workloads: list[TensorExpr], *, intrinsics, constraints,
                 "measure_failures": measure_failures,
                 "cache_data": dict(cache._data)})
 
+    if (measure and measure_opts.backend == "pallas"
+            and best_rank is not None and best_rank[0] > 0):
+        # on the chip an analytical stand-in is not a result: the committed
+        # latency would claim a measurement that never happened
+        raise RuntimeError(
+            f"codesign --measure on the chip: the best candidate "
+            f"({best.intrinsic}) has {best_rank[0]} workload(s) with no "
+            f"timed kernel; per-intrinsic summary: {measured_summary}")
+
     calibration = None
     saved_db = None
     if measure:

@@ -72,18 +72,12 @@ def activation_spec(spec: P | None, mesh=None):
 
 def _ambient_axes() -> tuple[str, ...] | None:
     """Axis names of the mesh active right now: the recorded install-time
-    axes, else the ambient ``with mesh:`` context (how the launchers trace
+    axes, else the ambient ``jax.set_mesh`` context (how the launchers trace
     their jitted steps)."""
     if _STATE.axes is not None:
         return _STATE.axes
-    try:
-        from jax.interpreters import pxla
-        mesh = pxla.thread_resources.env.physical_mesh
-        if not mesh.empty:
-            return tuple(mesh.axis_names)
-    except Exception:
-        pass
-    return None
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else tuple(mesh.axis_names)
 
 
 def _pruned(spec: P, axes: tuple[str, ...]) -> P:

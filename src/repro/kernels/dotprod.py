@@ -12,8 +12,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _dot_kernel(a_ref, b_ref, o_ref, acc_ref, *, n_k: int):
     k = pl.program_id(0)
@@ -52,7 +50,7 @@ def dot(a: jax.Array, b: jax.Array, *, bk: int = 2048,
         out_specs=pl.BlockSpec((1, 1), lambda kk: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 1), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, 1), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(a[None, :], b[None, :])

@@ -15,8 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _gemv_kernel(a_ref, x_ref, o_ref, acc_ref, *, n_k: int):
     k = pl.program_id(1)
@@ -55,7 +53,7 @@ def gemv(a: jax.Array, x: jax.Array, *, bm: int = 512, bk: int = 512,
         out_specs=pl.BlockSpec((bm, 1), lambda i, kk: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((mp, 1), a.dtype),
         scratch_shapes=[pltpu.VMEM((bm, 1), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, x[None, :])

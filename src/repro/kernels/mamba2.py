@@ -17,8 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _mamba2_kernel(x_ref, a_ref, b_ref, c_ref, h0_ref,
                    y_ref, hT_ref, state_ref, *, chunk: int, n_t: int):
@@ -33,25 +31,34 @@ def _mamba2_kernel(x_ref, a_ref, b_ref, c_ref, h0_ref,
     b = b_ref[0].astype(jnp.float32)               # (L, N)
     c = c_ref[0].astype(jnp.float32)               # (L, N)
 
-    la = jnp.cumsum(a[0])                          # (L,), inclusive
+    # inclusive prefix sums over time as lower-triangular-ones matmuls (the
+    # MXU form of cumsum, which has no Mosaic lowering), taken once as a
+    # column and once as a row so that no vector transpose is needed
+    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    tril = (si <= ti).astype(jnp.float32)
+    nt = (((1,), (1,)), ((), ()))
+    hi = jax.lax.Precision.HIGHEST
+    la_col = jax.lax.dot_general(tril, a, nt, precision=hi,
+                                 preferred_element_type=jnp.float32)  # (L, 1)
+    la_row = jax.lax.dot_general(a, tril, nt, precision=hi,
+                                 preferred_element_type=jnp.float32)  # (1, L)
+    la_last = la_col[chunk - 1:, :]                # (1, 1)
     h0 = state_ref[...]                            # (N, P)
 
     # inter-chunk
-    y = jnp.dot(c * jnp.exp(la)[:, None], h0,
-                preferred_element_type=jnp.float32)
+    y = jnp.dot(c * jnp.exp(la_col), h0, preferred_element_type=jnp.float32)
 
     # intra-chunk (inclusive diagonal: y_t uses h_t after its own update)
-    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.where(si <= ti,
-                      jnp.exp(jnp.minimum(la[:, None] - la[None, :], 0.0)),
+    decay = jnp.where(si <= ti, jnp.exp(jnp.minimum(la_col - la_row, 0.0)),
                       0.0)
-    gram = jnp.dot(c, b.T, preferred_element_type=jnp.float32) * decay
+    gram = jax.lax.dot_general(c, b, nt,
+                               preferred_element_type=jnp.float32) * decay
     y += jnp.dot(gram, x, preferred_element_type=jnp.float32)
 
     # state update
-    bd = b * jnp.exp(la[-1] - la)[:, None]
-    state_ref[...] = jnp.exp(la[-1]) * h0 + jnp.dot(
+    bd = b * jnp.exp(la_last - la_col)
+    state_ref[...] = jnp.exp(la_last) * h0 + jnp.dot(
         bd.T, x, preferred_element_type=jnp.float32)
 
     y_ref[0] = y.astype(y_ref.dtype)
@@ -76,18 +83,21 @@ def mamba2(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
     def flat(z):
         return jnp.moveaxis(z, 2, 1).reshape(bs * h, t, z.shape[-1])
 
+    n_t = t // chunk
     xf, bf, cf = flat(x), flat(b), flat(c)
-    af = jnp.moveaxis(a, 2, 1).reshape(bs * h, 1, t)
+    # one (1, chunk) row per (head, chunk): a block whose two minor dims are
+    # the array's own obeys the TPU's (8, 128) block rule at any chunk size
+    af = jnp.moveaxis(a, 2, 1).reshape(bs * h * n_t, 1, chunk)
     h0 = state.reshape(bs * h, n, p)
 
-    n_t = t // chunk
     grid = (bs * h, n_t)
     y, hT = pl.pallas_call(
         functools.partial(_mamba2_kernel, chunk=chunk, n_t=n_t),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, p), lambda bh, tt: (bh, tt, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda bh, tt: (bh, 0, tt)),
+            pl.BlockSpec((1, 1, chunk),
+                         lambda bh, tt: (bh * n_t + tt, 0, 0)),
             pl.BlockSpec((1, chunk, n), lambda bh, tt: (bh, tt, 0)),
             pl.BlockSpec((1, chunk, n), lambda bh, tt: (bh, tt, 0)),
             pl.BlockSpec((1, n, p), lambda bh, tt: (bh, 0, 0)),
@@ -101,7 +111,7 @@ def mamba2(x: jax.Array, a: jax.Array, b: jax.Array, c: jax.Array,
             jax.ShapeDtypeStruct((bs * h, n, p), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n, p), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(xf, af, bf, cf, h0)

@@ -1,7 +1,7 @@
 """Jit'd dispatch wrappers for all kernels.
 
-Every op takes ``implementation``: 'pallas' (the TPU kernel; on this CPU
-container only via interpret=True), 'interpret' (Pallas interpreter —
+Every op takes ``implementation``: 'pallas' (the compiled TPU kernel),
+'interpret' (Pallas interpreter —
 correctness path used by tests), or 'xla' (pure-jnp reference semantics,
 used by the dry-run so cost_analysis sees XLA-native HLO).
 
@@ -82,15 +82,15 @@ def _tuning_db():
 
 
 def _db_best(op: str, shape, dtype, implementation: str) -> dict[str, int]:
-    """Shape-exact tuned blocks from the DB (this backend, else the CPU
-    container's 'interpret' measurements), filtered to the op's known block
-    names so a malformed artifact can only narrow, never break, dispatch."""
+    """Shape-exact tuned blocks measured on this backend, filtered to the
+    op's known block names so a malformed artifact can only narrow, never
+    break, dispatch.  Blocks timed on another backend never stand in: the
+    interpreter accepts tiles the chip's compiler refuses."""
     db = _tuning_db()
     if db is None:
         return {}
     dt = str(jnp.dtype(dtype))
-    rec = (db.best_config(op, shape, dt, implementation)
-           or db.best_config(op, shape, dt, "interpret")) or {}
+    rec = db.best_config(op, shape, dt, implementation) or {}
     return {k: v for k, v in rec.items() if k in DEFAULT_BLOCKS[op]}
 
 

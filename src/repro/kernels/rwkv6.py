@@ -21,8 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .compat import CompilerParams
-
 
 def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
                   o_ref, sT_ref, state_ref, *, chunk: int, n_t: int):
@@ -38,21 +36,26 @@ def _rwkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     w = w_ref[0].astype(jnp.float32)               # (L, Dk) log-decay <= 0
     u = u_ref[0].astype(jnp.float32)               # (1, Dk)
 
-    lw = jnp.cumsum(w, axis=0)                     # inclusive
+    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # inclusive prefix sum over time as a lower-triangular-ones matmul: the
+    # MXU form of cumsum, which has no Mosaic lowering
+    tril = (si <= ti).astype(jnp.float32)
+    lw = jnp.dot(tril, w, precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)
     aq = lw - w                                    # exclusive
     s0 = state_ref[...]                            # (Dk, Dv)
 
     # inter-chunk: query against the carried state
     o = jnp.dot(r * jnp.exp(aq), s0, preferred_element_type=jnp.float32)
 
-    # intra-chunk: pairwise decay tensor, strictly-lower-triangular
-    ti = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    si = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    strict = (si < ti)[..., None]                  # (L, L, 1)
-    expo = aq[:, None, :] - lw[None, :, :]         # (L, L, Dk), <= 0 where s<t
-    pair = jnp.where(strict, jnp.exp(jnp.minimum(expo, 0.0)), 0.0)
-    scores = jnp.sum(pair * r[:, None, :] * k[None, :, :], axis=-1)
-    o += jnp.dot(scores, v, preferred_element_type=jnp.float32)
+    # intra-chunk, one source position s at a time (Mosaic lays out no
+    # (L, L, Dk) pairwise tensor): exponents aq_t - lw_s <= 0 where s < t
+    t_col = ti[:, :1]                              # (L, 1) query position
+    for src in range(chunk - 1):
+        pair = jnp.exp(jnp.minimum(aq - lw[src:src + 1], 0.0))   # (L, Dk)
+        score = jnp.sum(r * k[src:src + 1] * pair, axis=1, keepdims=True)
+        o += jnp.where(t_col > src, score, 0.0) * v[src:src + 1]
 
     # current-token bonus (diag(u))
     o += jnp.sum(r * u * k, axis=-1, keepdims=True) * v
@@ -112,7 +115,7 @@ def rwkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
             jax.ShapeDtypeStruct((b * h, dk, dv), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dk, dv), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(rf, kf, vf, wf, uf, s0)

@@ -183,7 +183,7 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
         set_activation_spec(DEFAULT_TRAIN_SPEC, mesh)
     t0 = time.time()
     fn, args, in_sh, out_sh, donate = build_cell(cfg, shape, mesh, impl, mode)
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(fn, in_shardings=in_sh, out_shardings=out_sh,
                          donate_argnums=donate)
         lowered = jitted.lower(*args)
@@ -195,8 +195,6 @@ def lower_cell(arch: str, shape_name: str, *, multi_pod: bool,
 
     ma = compiled.memory_analysis()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):  # jax < 0.5 returns [dict] per device
-        ca = ca[0] if ca else {}
     hlo = compiled.as_text()
     rep = hlo_analysis.analyze(hlo)
     n_dev = mesh.size

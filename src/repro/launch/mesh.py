@@ -8,12 +8,23 @@ jax device state.  The dry-run launches with
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axes.  jax >= 0.7 defaults to Explicit
+    axes, under which the GSPMD-style in/out shardings and activation
+    constraints this code base relies on are refused (e.g. a
+    ``ShardingTypeError`` in the embedding gather)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(*, tp: int = 1):
@@ -25,7 +36,7 @@ def make_host_mesh(*, tp: int = 1):
     if tp < 1 or n % tp:
         raise ValueError(f"tp={tp} must be >= 1 and divide the host device "
                          f"count ({n})")
-    return jax.make_mesh((n // tp, tp), ("data", "model"))
+    return make_mesh((n // tp, tp), ("data", "model"))
 
 
 def describe(mesh) -> str:

@@ -35,12 +35,14 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro import obs
 from repro.configs import ARCH_IDS, get_config
 from repro.distributed.sharding import named, param_shardings, tp_size
 from repro.ft import ProgressWatchdog, inject
 from repro.ft.inject import InjectedFault
+from repro.launch import compile_cache
 from repro.launch.mesh import describe, make_host_mesh
 from repro.launch.paging import PageAllocator, PriorityScheduler
 from repro.launch.steps import make_decode_step, make_prefill_step
@@ -284,6 +286,32 @@ def _resolve_mesh_tp(mesh, tp: int) -> int:
     return mtp
 
 
+def _init_sharded(init, specs, mesh):
+    """Run ``init`` (parameters or a cache) as one jitted program whose
+    outputs land laid out by ``specs`` over ``mesh`` (or on the default
+    device without one): nothing is built whole on one device and then
+    placed."""
+    out = None if mesh is None else named(specs, mesh)
+    return jax.jit(init, out_shardings=out)()
+
+
+def init_params(cfg, key, *, tp: int = 1, mesh=None):
+    """Model parameters for serving, sharded over ``mesh`` from the start."""
+    mod = family_module(cfg)
+    return _init_sharded(functools.partial(mod.init, cfg, key, tp=tp),
+                         mod.specs(cfg), mesh)
+
+
+def _prefill_cache_specs(mod, cfg):
+    """Specs of a batch-1 prefill cache: the dense cache's, with the slot
+    axis unsharded (one request cannot split over 'data')."""
+    def one(spec, ax):
+        return P(*(None if i == ax else e for i, e in enumerate(spec)))
+    return jax.tree_util.tree_map(one, mod.cache_specs(cfg),
+                                  mod.cache_slot_axes(cfg),
+                                  is_leaf=lambda x: isinstance(x, P))
+
+
 @functools.lru_cache(maxsize=None)
 def _mesh_jitted_steps(cfg, tp: int, impl: str, max_seq: int, mesh):
     """Mesh-aware :func:`_jitted_steps`: identical programs, but decode and
@@ -338,13 +366,12 @@ class ServeEngine:
                 params, param_shardings(self.mod, cfg, mesh))
             self._decode, self._prefill, self._write_slot = \
                 _mesh_jitted_steps(cfg, tp, impl, max_seq, mesh)
-            self.cache = jax.device_put(
-                self.mod.init_cache(cfg, slots, max_seq, tp),
-                named(self.mod.cache_specs(cfg), mesh))
         else:
             self._decode, self._prefill, self._write_slot = _jitted_steps(
                 cfg, tp, impl, max_seq)
-            self.cache = self.mod.init_cache(cfg, slots, max_seq, tp)
+        self.cache = _init_sharded(
+            functools.partial(self.mod.init_cache, cfg, slots, max_seq, tp),
+            self.mod.cache_specs(cfg), mesh)
         self.pos = np.zeros(slots, np.int64)   # per-slot next write position
         self.clock = clock
         self.stall_limit = stall_limit
@@ -527,15 +554,16 @@ def _paged_jitted_steps(cfg, tp: int, impl: str):
 
 @functools.lru_cache(maxsize=None)
 def _mesh_paged_jitted_steps(cfg, tp: int, impl: str, mesh):
-    """Mesh-aware :func:`_paged_jitted_steps` for the batched-decode and
-    commit programs only: both pin the paged cache's output sharding (pool
-    kv-heads over 'model', physical rows replicated) so decode steps can
-    never de-shard it.  Chunked prefill keeps using the plain decode jit —
-    its private batch-1 dense cache is a different pytree, laid out by
-    GSPMD from the sharded params."""
+    """Mesh-aware :func:`_paged_jitted_steps`: the batched-decode and
+    commit programs pin the paged cache's output sharding (pool kv-heads
+    over 'model', physical rows replicated) so decode steps can never
+    de-shard it, and the chunked-prefill program pins its private batch-1
+    dense cache to :func:`_prefill_cache_specs`, so no prefill chunk
+    gathers weights or KV onto one device."""
     mod = family_module(cfg)
     axes = mod.paged_slot_axes(cfg)
     c_sh = named(mod.paged_cache_specs(cfg), mesh)
+    pf_sh = named(_prefill_cache_specs(mod, cfg), mesh)
 
     def write_slot(cache, packed, slot, prows):
         def wr(c, pc, ax):
@@ -546,9 +574,10 @@ def _mesh_paged_jitted_steps(cfg, tp: int, impl: str, mesh):
                 c, jax.lax.index_in_dim(pc, 0, ax, keepdims=False), slot, ax)
         return jax.tree_util.tree_map(wr, cache, packed, axes)
 
-    decode = jax.jit(make_decode_step(cfg, tp=tp, impl=impl),
-                     out_shardings=(None, c_sh))
-    return decode, jax.jit(write_slot, out_shardings=c_sh)
+    step = make_decode_step(cfg, tp=tp, impl=impl)
+    decode = jax.jit(step, out_shardings=(None, c_sh))
+    prefill = jax.jit(step, out_shardings=(None, pf_sh))
+    return decode, prefill, jax.jit(write_slot, out_shardings=c_sh)
 
 
 @dataclasses.dataclass
@@ -601,20 +630,20 @@ class PagedServeEngine:
             n_pages = -(-max_seq // page_size) * slots
         self.alloc = PageAllocator(n_pages, page_size)
         self.scheduler = PriorityScheduler(slots, max_concurrency, age_steps)
-        # chunked prefill always runs the plain decode jit on its private
-        # dense cache; batched decode + commit swap in mesh-aware programs
-        # (pinned cache shardings) when a mesh hosts the engine
+        # chunked prefill (self._decode, on a private dense cache) and
+        # batched decode run the same step; on a mesh each program pins its
+        # own cache's sharding
         self._decode, self._write_slot, self._axes = _paged_jitted_steps(
             cfg, tp, impl)
         self._decode_batch = self._decode
-        self._has_pool = "pool" in jax.tree_util.tree_leaves(self._axes)
-        self.cache = self.mod.init_paged_cache(
-            cfg, slots, n_pages * page_size, max_seq, tp)
         if mesh is not None:
-            self._decode_batch, self._write_slot = _mesh_paged_jitted_steps(
-                cfg, tp, impl, mesh)
-            self.cache = jax.device_put(
-                self.cache, named(self.mod.paged_cache_specs(cfg), mesh))
+            self._decode_batch, self._decode, self._write_slot = \
+                _mesh_paged_jitted_steps(cfg, tp, impl, mesh)
+        self._has_pool = "pool" in jax.tree_util.tree_leaves(self._axes)
+        self.cache = _init_sharded(
+            functools.partial(self.mod.init_paged_cache, cfg, slots,
+                              n_pages * page_size, max_seq, tp),
+            self.mod.paged_cache_specs(cfg), mesh)
         self.row_map = np.full((slots, max_seq), -1, np.int32)
         # pos sentinel max_seq: an idle/prefilling slot's decode-batch lane
         # writes out of range, which the paged scatter drops (DESIGN.md §12)
@@ -836,8 +865,13 @@ class PagedServeEngine:
             self._swap_in(slot, req)
             return
         self.pos[slot] = self.max_seq
-        self._prefills[slot] = _Prefill(req, self.mod.init_prefill_cache(
-            self.cfg, 1, len(req.prompt), self._tp))
+        init = functools.partial(self.mod.init_prefill_cache, self.cfg, 1,
+                                 len(req.prompt), self._tp)
+        self._prefills[slot] = _Prefill(req, init() if self.mesh is None
+                                        else _init_sharded(
+                                            init, _prefill_cache_specs(
+                                                self.mod, self.cfg),
+                                            self.mesh))
 
     def _admit_new(self) -> None:
         """Admit waiting requests in (effective priority, submit) order,
@@ -1250,6 +1284,7 @@ def main() -> None:
     ap.add_argument("--trace-out", default=None,
                     help="Chrome trace path (default: artifacts/trace.json)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     if args.trace:
         obs.enable()
@@ -1287,8 +1322,8 @@ def main() -> None:
         mesh = None
         make_host_mesh()
     tp = tp_size(mesh) if mesh is not None else 1
-    mod = family_module(cfg)
-    params = mod.init(cfg, jax.random.PRNGKey(args.seed), tp=tp)
+    params = init_params(cfg, jax.random.PRNGKey(args.seed), tp=tp,
+                         mesh=mesh)
     requests = make_requests(cfg, args.requests, args.max_new, args.seed,
                              long_every=args.long_every)
     if args.deadline_s is not None:

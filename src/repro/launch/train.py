@@ -29,6 +29,7 @@ from repro.data.pipeline import Prefetcher, SyntheticLM
 from repro.distributed.context import DEFAULT_TRAIN_SPEC, set_activation_spec
 from repro.distributed.sharding import batch_specs, named
 from repro.ft import CheckpointManager, Watchdog
+from repro.launch import compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.launch.steps import make_train_step
 from repro.models import family_module, reduced
@@ -60,6 +61,7 @@ def main() -> None:
                     help="co-design app whose tuned kernel blocks to "
                          "install (default: the arch name)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
     if args.smoke:
@@ -102,7 +104,7 @@ def main() -> None:
         start = latest + 1
         print(f"resumed from checkpoint step {latest}")
     else:
-        with mesh:
+        with jax.set_mesh(mesh):
             params = mod.init(cfg, key, tp=tp)
             opt_state = opt.init(params)
 

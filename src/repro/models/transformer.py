@@ -83,10 +83,6 @@ def _block(p: Params, cfg: ModelConfig, x, *, positions, tp, impl, window,
 # Whole model
 # ---------------------------------------------------------------------------
 
-def _stack(trees: list[Params]) -> Params:
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
-
-
 def _n_groups(cfg: ModelConfig) -> int:
     return cfg.n_layers // 2 if cfg.alt_local_global else cfg.n_layers
 
@@ -94,12 +90,14 @@ def _n_groups(cfg: ModelConfig) -> int:
 def init(cfg: ModelConfig, key, tp: int = 1) -> Params:
     dtype = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, cfg.n_layers + 3)
-    blocks = [_block_init(keys[i], cfg, tp, dtype)
-              for i in range(cfg.n_layers)]
+    # vmap over the layer keys: the same values as a per-layer loop, built
+    # stacked (one layer's program to compile, and no unstacked copy)
+    blocks = jax.vmap(lambda k: _block_init(k, cfg, tp, dtype))
     if cfg.alt_local_global:
-        layers = {"local": _stack(blocks[0::2]), "global": _stack(blocks[1::2])}
+        layers = {"local": blocks(keys[0:cfg.n_layers:2]),
+                  "global": blocks(keys[1:cfg.n_layers:2])}
     else:
-        layers = {"all": _stack(blocks)}
+        layers = {"all": blocks(keys[:cfg.n_layers])}
     p: Params = {
         "embed": L.embed_init(keys[-3], cfg, tp, dtype),
         "layers": layers,

@@ -25,6 +25,7 @@ from pathlib import Path
 from repro.core import workloads as W
 from repro.core.codesign import Constraints, codesign
 from repro.core.tst import TensorExpr
+from repro.launch import compile_cache
 
 from .db import DEFAULT_DB_PATH
 from .measure import MeasureOptions
@@ -73,7 +74,9 @@ def main(argv: list[str] | None = None) -> int:
                     help="re-rank the frontier by real kernel timings")
     ap.add_argument("--backend", default="interpret",
                     choices=["interpret", "pallas", "xla"],
-                    help="measurement backend (interpret on CPU containers)")
+                    help="measurement backend: interpret on a CPU host, "
+                         "pallas on a TPU (fails unless every workload of "
+                         "the committed solution was timed)")
     ap.add_argument("--top-k", type=int, default=3,
                     help="feasible Pareto candidates to measure per intrinsic")
     ap.add_argument("--warmup", type=int, default=2)
@@ -93,6 +96,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="tiny GEMM preset; exit non-zero unless a "
                          "calibrated model is produced (CI gate)")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.smoke:
         args.workload = args.workload or ["gemm:64,64,64"]

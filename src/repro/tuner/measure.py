@@ -17,8 +17,9 @@ resource envelope):
     intrinsic block (the cost model's ``ptile``), so measurement is
     sensitive to both the schedule's split factors and the accelerator's
     array shape;
-  * on this CPU container kernels run with ``implementation='interpret'``;
-    on a real TPU pass ``backend='pallas'``.
+  * on a CPU host kernels run with ``implementation='interpret'``; on a
+    TPU pass ``backend='pallas'``, where candidates whose blocks the chip's
+    compiler would refuse are skipped as statically illegal.
 
 Failures (unloweable workload, shape/compile errors, kernel crashes) are
 *captured*: a failed candidate yields ``MeasureResult(latency_s=inf,
@@ -106,7 +107,8 @@ class MeasureOptions:
     repeats: int = 5
     # cap on the padded-tile volume a single kernel invocation may claim —
     # guards the host against a schedule that pads a tile to an enormous
-    # block (interpret mode would happily allocate it)
+    # block (interpret mode would happily allocate it); not applied on
+    # backend "pallas"
     max_block_elems: int = 1 << 24
     # bounded retry for kernel-timing failures (transient crashes / flaky
     # backends); lowering errors are structural and never retried
@@ -194,66 +196,75 @@ def padded_tiles(workload: TensorExpr, hw: HWConfig,
 # ---------------------------------------------------------------------------
 
 
-def lower(workload: TensorExpr, hw: HWConfig, schedule: Schedule,
-          opts: MeasureOptions) -> tuple[KernelPoint, Callable]:
-    """-> (point, thunk) where ``thunk()`` runs the kernel once and blocks.
+_BLOCK_ROLES = {"gemm": "mnk", "gemv": "mk", "dot": "k", "conv2d": "k"}
+_SHAPE_ROLES = {"gemm": "mnk", "gemv": "mk", "dot": "k", "conv2d": "kcxyrs"}
 
-    Raises ValueError for workloads with no kernel lowering; the batch
-    driver converts that into a failed MeasureResult.
-    """
+
+def kernel_point(workload: TensorExpr, hw: HWConfig, schedule: Schedule,
+                 opts: MeasureOptions) -> KernelPoint:
+    """The kernel invocation a candidate lowers to: its op, canonical problem
+    shape, and blocks (each the padded interface tile clamped to the loop
+    extent).  Raises ValueError for workloads with no kernel lowering."""
     cls = classify(workload)
     if cls is None:
         raise ValueError(f"no kernel lowering for workload {workload.name!r}")
     op, roles = cls
     ext = workload.extents
     pt = padded_tiles(workload, hw, schedule)
+    shape = tuple(ext[roles[r]] for r in _SHAPE_ROLES[op])
+    blocks = {f"b{r}": min(pt[roles[r]], ext[roles[r]])
+              for r in _BLOCK_ROLES[op]}
+
+    import jax.numpy as jnp
+    return KernelPoint(op, shape, str(jnp.dtype(opts.dtype)), opts.backend,
+                       tuple(sorted(blocks.items())))
+
+
+def lower(workload: TensorExpr, hw: HWConfig, schedule: Schedule,
+          opts: MeasureOptions) -> tuple[KernelPoint, Callable]:
+    """-> (point, thunk) where ``thunk()`` runs the kernel once and blocks.
+
+    Raises ValueError for workloads with no kernel lowering;
+    :func:`measure_one` and :func:`measure_batch` turn that into a failed
+    MeasureResult.
+    """
+    point = kernel_point(workload, hw, schedule, opts)
+    vol = 1
+    for v in padded_tiles(workload, hw, schedule).values():
+        vol *= v
+    # the cap guards the host's memory in interpret mode; a compiled kernel
+    # holds only its blocks, which the legality gate bounds by VMEM
+    if opts.backend != "pallas" and vol > opts.max_block_elems:
+        raise ValueError(f"padded tile volume {vol} exceeds "
+                         f"max_block_elems={opts.max_block_elems}")
 
     import jax
     import jax.numpy as jnp
 
     from repro.kernels import ops
 
-    dtype = jnp.dtype(opts.dtype)
     rng = np.random.default_rng(0)
 
     def arr(*shape):
-        return jnp.asarray(rng.standard_normal(shape), dtype)
+        return jnp.asarray(rng.standard_normal(shape), point.dtype)
 
-    if op == "gemm":
-        m, n, k = (ext[roles[r]] for r in ("m", "n", "k"))
-        blocks = {"bm": min(pt[roles["m"]], m), "bn": min(pt[roles["n"]], n),
-                  "bk": min(pt[roles["k"]], k)}
-        shape: tuple[int, ...] = (m, n, k)
+    blocks, impl = point.block_map, opts.backend
+    if point.op == "gemm":
+        m, n, k = point.shape
         a, b = arr(m, k), arr(k, n)
-        fn = lambda: ops.matmul(a, b, implementation=opts.backend, **blocks)
-    elif op == "gemv":
-        m, k = ext[roles["m"]], ext[roles["k"]]
-        blocks = {"bm": min(pt[roles["m"]], m), "bk": min(pt[roles["k"]], k)}
-        shape = (m, k)
+        fn = lambda: ops.matmul(a, b, implementation=impl, **blocks)
+    elif point.op == "gemv":
+        m, k = point.shape
         a, x = arr(m, k), arr(k)
-        fn = lambda: ops.matvec(a, x, implementation=opts.backend, **blocks)
-    elif op == "dot":
-        k = ext[roles["k"]]
-        blocks = {"bk": min(pt[roles["k"]], k)}
-        shape = (k,)
+        fn = lambda: ops.matvec(a, x, implementation=impl, **blocks)
+    elif point.op == "dot":
+        (k,) = point.shape
         a, b = arr(k), arr(k)
-        fn = lambda: ops.dot(a, b, implementation=opts.backend, **blocks)
+        fn = lambda: ops.dot(a, b, implementation=impl, **blocks)
     else:  # conv2d
-        kk, c, x, y, r, s = (ext[roles[t]] for t in "kcxyrs")
-        blocks = {"bk": min(pt[roles["k"]], kk)}
-        shape = (kk, c, x, y, r, s)
+        kk, c, x, y, r, s = point.shape
         a, w = arr(c, x + r - 1, y + s - 1), arr(kk, c, r, s)
-        fn = lambda: ops.conv2d(a, w, implementation=opts.backend, **blocks)
-
-    vol = 1
-    for v in pt.values():
-        vol *= v
-    if vol > opts.max_block_elems:
-        raise ValueError(f"padded tile volume {vol} exceeds "
-                         f"max_block_elems={opts.max_block_elems}")
-
-    point = KernelPoint(op, shape, str(dtype), opts.backend,
-                        tuple(sorted(blocks.items())))
+        fn = lambda: ops.conv2d(a, w, implementation=impl, **blocks)
     return point, lambda: jax.block_until_ready(fn())
 
 
@@ -318,9 +329,17 @@ def _static_illegal(workload: TensorExpr, hw: HWConfig, schedule: Schedule,
     if schedule.choice.workload_name != workload.name:
         return []
     from repro.analysis.findings import errors
-    from repro.analysis.legality import verify_candidate
-    return errors(verify_candidate(workload, schedule, hw,
-                                   max_block_elems=opts.max_block_elems))
+    from repro.analysis.legality import verify_candidate, verify_kernel_blocks
+    bad = errors(verify_candidate(workload, schedule, hw,
+                                  max_block_elems=opts.max_block_elems))
+    if bad or opts.backend != "pallas" or classify(workload) is None:
+        return bad
+    # the chip's compiler refuses blocks the interpreter accepts: gate them
+    # here rather than burn a compile (and a failure record) on each
+    import jax.numpy as jnp
+    pt = kernel_point(workload, hw, schedule, opts)
+    return errors(verify_kernel_blocks(pt.op, pt.shape, pt.block_map,
+                                       jnp.dtype(pt.dtype).itemsize))
 
 
 def _illegal_result(findings: list, workload: TensorExpr) -> MeasureResult:

@@ -16,12 +16,13 @@ from repro.distributed.context import (DEFAULT_TRAIN_SPEC, activation_spec,
                                        get_activation_spec,
                                        set_activation_spec)
 from repro.distributed.sharding import batch_specs, named, prune_specs
+from repro.launch.mesh import make_mesh
 
 SRC = str(Path(__file__).resolve().parents[1] / "src")
 
 
 def test_prune_specs_drops_missing_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     tree = {"a": P(("pod", "data"), "model"), "b": P("pod"), "c": P(None)}
     got = prune_specs(tree, mesh)
     assert got["a"] == P("data", "model")
@@ -30,7 +31,7 @@ def test_prune_specs_drops_missing_axes():
 
 
 def test_named_builds_shardings():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = named({"w": P("model", "data")}, mesh)
     assert sh["w"].mesh.shape == {"data": 1, "model": 1}
 
@@ -50,7 +51,7 @@ def test_activation_context_noop_when_unset():
 
 
 def test_activation_spec_context_manager_scopes_and_restores():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     set_activation_spec(P("data", None, None), mesh)
     with activation_spec(DEFAULT_TRAIN_SPEC, mesh):
         # pruned against the install mesh's axes ('pod' dropped)
@@ -67,9 +68,9 @@ def test_activation_spec_installed_without_mesh_prunes_lazily():
     at apply time against the mesh actually active."""
     import jax.numpy as jnp
     set_activation_spec(DEFAULT_TRAIN_SPEC)   # no mesh: raw spec stored
-    mesh = jax.make_mesh((1, 1), ("data", "model"))   # podless
+    mesh = make_mesh((1, 1), ("data", "model"))   # podless
     x = jnp.ones((2, 4, 8))
-    with mesh:
+    with jax.set_mesh(mesh):
         out = jax.jit(constrain_activations)(x)
     assert out.shape == x.shape
     assert float(out.sum()) == float(x.sum())
@@ -108,7 +109,8 @@ _MULTIDEV = textwrap.dedent("""
 
     # --- pipeline parallelism over 4 stages -----------------------------
     from repro.distributed.pipeline import pipeline_apply
-    mesh = jax.make_mesh((4,), ("stage",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((4,), ("stage",))
     S, B, D = 4, 8, 16
     key = jax.random.PRNGKey(0)
     ws = jax.random.normal(key, (S, D, D)) * 0.3
@@ -130,11 +132,11 @@ _MULTIDEV = textwrap.dedent("""
     import tempfile
     tmp = tempfile.mkdtemp()
     mgr = CheckpointManager(tmp)
-    mesh8 = jax.make_mesh((8, 1), ("data", "model"))
+    mesh8 = make_mesh((8, 1), ("data", "model"))
     sharding = jax.sharding.NamedSharding(mesh8, P("data", None))
     arr = jax.device_put(jnp.arange(32.0).reshape(8, 4), sharding)
     mgr.save(1, {"w": arr})
-    mesh2 = jax.make_mesh((2, 1), ("data", "model"))
+    mesh2 = make_mesh((2, 1), ("data", "model"))
     got = mgr.restore(1, like={"w": jax.ShapeDtypeStruct((8, 4), jnp.float32)},
                       mesh=mesh2, specs={"w": P("data", None)})
     assert got["w"].sharding.mesh.shape["data"] == 2

@@ -117,7 +117,8 @@ def test_prefetcher_order():
 
 
 def test_data_global_arrays_shard_over_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("data", "model"))
     d = SyntheticLM(vocab=50, seq_len=4, global_batch=4, seed=1)
     arrs = d.global_arrays(0, mesh)
     assert arrs["tokens"].shape == (4, 4)

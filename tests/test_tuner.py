@@ -114,6 +114,54 @@ def test_measure_batch_lowers_only_legal_candidates(gemm64):
     assert s["measured"] == 2 and s["deduped"] == 1 and s["failed"] == 0
 
 
+def test_pallas_backend_gates_blocks_the_chip_refuses(gemm64, monkeypatch):
+    """(16, 16, 16) blocks of a 64^3 GEMM break the (8, 128) tiling rule:
+    the interpreter runs them, the chip's compiler would not, so on backend
+    'pallas' they are skipped unrun; whole-array (64, 64, 64) blocks pass."""
+    wl, choice = gemm64
+    monkeypatch.setattr(M, "lower", lambda *a, **k: (_ for _ in ()).throw(
+        AssertionError("illegal candidate was lowered")))
+    res = M.measure_one(wl, _hw(), _sched(wl, choice, 16),
+                        M.MeasureOptions(backend="pallas"))
+    assert res.error_type == "Illegal" and "legality/block-tiling" in res.error
+    pt = M.kernel_point(wl, _hw(), _sched(wl, choice, 64),
+                        M.MeasureOptions(backend="pallas"))
+    assert pt.block_map == {"bm": 64, "bn": 64, "bk": 64}
+    assert M._static_illegal(wl, _hw(), _sched(wl, choice, 64),
+                             M.MeasureOptions(backend="pallas")) == []
+
+
+@pytest.mark.parametrize("op,shape,blocks,rule", [
+    ("gemm", (512, 1536, 1536), {"bm": 16, "bn": 48, "bk": 64},
+     "legality/block-tiling"),
+    ("gemm", (2048, 3072, 3072), {"bm": 512, "bn": 1024, "bk": 1024},
+     "legality/block-vmem"),
+    ("gemm", (2048, 3072, 3072), {"bm": 256, "bn": 1024, "bk": 1024}, None),
+    ("gemv", (12288, 4096), {"bm": 512, "bk": 512}, None),
+    ("dot", (1000,), {"bk": 64}, "legality/block-tiling"),
+    ("conv2d", (64, 64, 56, 56, 3, 3), {"bk": 64}, None),
+    ("conv2d", (64, 64, 56, 56, 3, 3), {"bk": 12}, "legality/block-tiling"),
+])
+def test_verify_kernel_blocks_matches_v5e_compiles(op, shape, blocks, rule):
+    """Each case is what a v5e compile of the kernel accepted or refused."""
+    from repro.analysis.legality import verify_kernel_blocks
+    found = [f.rule for f in verify_kernel_blocks(op, shape, blocks, 4)]
+    assert set(found) == ({rule} if rule else set())
+
+
+def test_codesign_on_chip_refuses_analytical_standins(monkeypatch):
+    """Backend 'pallas' with nothing timeable must fail, not commit a
+    latency that no kernel measured."""
+    from repro.tuner import measure as M_
+
+    monkeypatch.setattr(M_, "measure_one", lambda *a, **k: M_.MeasureResult(
+        latency_s=math.inf, error="forced failure"))
+    with pytest.raises(RuntimeError, match="no timed kernel"):
+        codesign([W.gemm(64, 64, 64, name="g0")], intrinsics=["GEMM"],
+                 n_trials=4, n_init=2, seed=0, target="tpu", measure=True,
+                 measure_backend="pallas", measure_top_k=2)
+
+
 def test_illegal_skip_never_retried_or_quarantined(gemm64, monkeypatch):
     wl, choice = gemm64
     calls = []
@@ -131,6 +179,10 @@ def test_measure_batch_mixes_failures_and_successes(gemm64):
     opts = M.MeasureOptions(warmup=0, repeats=1, max_block_elems=8)
     out = M.measure_batch(wl, _hw(), [good], opts)   # volume cap trips
     assert len(out) == 1 and not out[0].ok and "max_block_elems" in out[0].error
+    # on the chip the cap does not apply (VMEM bounds the blocks there)
+    pt, _ = M.lower(wl, _hw(), good, M.MeasureOptions(backend="pallas",
+                                                      max_block_elems=8))
+    assert pt.block_map == {"bm": 32, "bn": 32, "bk": 32}
 
 
 # ---------------------------------------------------------------------------
