@@ -527,14 +527,28 @@ class ServeEngine:
         return sorted(done, key=lambda r: r.rid)
 
 
+def _named_program(step, name: str):
+    """``step`` under the function name ``name``, which jax.jit makes the
+    program's name (``jit_<name>``): the paged engine's batched decode and
+    its chunked prefill run one step function as two programs that a
+    profiler trace tells apart, both named ``decode_step...``."""
+    @functools.wraps(step)
+    def program(*args, **kwargs):
+        return step(*args, **kwargs)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
 @functools.lru_cache(maxsize=None)
 def _paged_jitted_steps(cfg, tp: int, impl: str):
     """Jitted paged-engine programs per (config, tp, impl), shared across
-    engine instances like :func:`_jitted_steps`.  jax.jit additionally keys
-    the decode program on the page-table width and the write program on the
-    packed prompt length."""
+    engine instances like :func:`_jitted_steps`: the batched decode
+    (``jit_decode_step_batch``), the commit write and the cache's slot
+    axes.  jax.jit additionally keys the decode program on the page-table
+    width and the write program on the packed prompt length."""
     mod = family_module(cfg)
-    decode = jax.jit(make_decode_step(cfg, tp=tp, impl=impl))
+    decode = jax.jit(_named_program(make_decode_step(cfg, tp=tp, impl=impl),
+                                    "decode_step_batch"))
     axes = mod.paged_slot_axes(cfg)
 
     def write_slot(cache, packed, slot, prows):
@@ -550,6 +564,14 @@ def _paged_jitted_steps(cfg, tp: int, impl: str):
         return jax.tree_util.tree_map(wr, cache, packed, axes)
 
     return decode, jax.jit(write_slot), axes
+
+
+@functools.lru_cache(maxsize=None)
+def _paged_prefill_step(cfg, tp: int, impl: str):
+    """The chunked prefill (``jit_decode_step_prefill``): the same step as
+    the batched decode, on a private batch-1 dense cache."""
+    return jax.jit(_named_program(make_decode_step(cfg, tp=tp, impl=impl),
+                                  "decode_step_prefill"))
 
 
 @functools.lru_cache(maxsize=None)
@@ -575,8 +597,10 @@ def _mesh_paged_jitted_steps(cfg, tp: int, impl: str, mesh):
         return jax.tree_util.tree_map(wr, cache, packed, axes)
 
     step = make_decode_step(cfg, tp=tp, impl=impl)
-    decode = jax.jit(step, out_shardings=(None, c_sh))
-    prefill = jax.jit(step, out_shardings=(None, pf_sh))
+    decode = jax.jit(_named_program(step, "decode_step_batch"),
+                     out_shardings=(None, c_sh))
+    prefill = jax.jit(_named_program(step, "decode_step_prefill"),
+                      out_shardings=(None, pf_sh))
     return decode, prefill, jax.jit(write_slot, out_shardings=c_sh)
 
 
@@ -631,11 +655,11 @@ class PagedServeEngine:
         self.alloc = PageAllocator(n_pages, page_size)
         self.scheduler = PriorityScheduler(slots, max_concurrency, age_steps)
         # chunked prefill (self._decode, on a private dense cache) and
-        # batched decode run the same step; on a mesh each program pins its
-        # own cache's sharding
-        self._decode, self._write_slot, self._axes = _paged_jitted_steps(
-            cfg, tp, impl)
-        self._decode_batch = self._decode
+        # batched decode run the same step as two named programs; on a mesh
+        # each program pins its own cache's sharding
+        self._decode_batch, self._write_slot, self._axes = \
+            _paged_jitted_steps(cfg, tp, impl)
+        self._decode = _paged_prefill_step(cfg, tp, impl)
         if mesh is not None:
             self._decode_batch, self._decode, self._write_slot = \
                 _mesh_paged_jitted_steps(cfg, tp, impl, mesh)
@@ -861,17 +885,19 @@ class PagedServeEngine:
         # lifecycle, not a fresh admission
         _obs_admit(req, slot,
                    resumed=req.rid in self._suspended or req.preemptions > 0)
+        args = {"rid": req.rid} if obs.active() else None
         if req.rid in self._suspended:
-            self._swap_in(slot, req)
+            with obs.span("serve.swap_in", args):
+                self._swap_in(slot, req)
             return
         self.pos[slot] = self.max_seq
         init = functools.partial(self.mod.init_prefill_cache, self.cfg, 1,
                                  len(req.prompt), self._tp)
-        self._prefills[slot] = _Prefill(req, init() if self.mesh is None
-                                        else _init_sharded(
-                                            init, _prefill_cache_specs(
-                                                self.mod, self.cfg),
-                                            self.mesh))
+        with obs.span("serve.prefill_init", args):
+            self._prefills[slot] = _Prefill(
+                req, init() if self.mesh is None else _init_sharded(
+                    init, _prefill_cache_specs(self.mod, self.cfg),
+                    self.mesh))
 
     def _admit_new(self) -> None:
         """Admit waiting requests in (effective priority, submit) order,
@@ -920,7 +946,9 @@ class PagedServeEngine:
                 continue
             chunk = min(self.prefill_chunk, len(req.prompt) - pf.done)
             toks = jnp.asarray(req.prompt[None, pf.done:pf.done + chunk])
-            with obs.span("serve.prefill_chunk"):
+            with obs.span("serve.prefill_chunk",
+                          {"rid": req.rid, "tokens": chunk}
+                          if obs.active() else None):
                 logits, pf.cache = self._decode(
                     self.params, pf.cache, toks, jnp.asarray([pf.done],
                                                              jnp.int32))
@@ -935,7 +963,10 @@ class PagedServeEngine:
             if pf.done < len(req.prompt):
                 continue
             del self._prefills[slot]
-            self._commit(slot, req, pf.cache, logits, finished)
+            with obs.span("serve.commit",
+                          {"rid": req.rid, "rows": len(req.prompt)}
+                          if obs.active() else None):
+                self._commit(slot, req, pf.cache, logits, finished)
 
     def _commit(self, slot: int, req: Request, pcache, logits,
                 finished: list[Request]) -> None:
@@ -998,37 +1029,45 @@ class PagedServeEngine:
         # injection site FIRST — nothing is mutated yet, so step() can drop
         # the whole tick as a transient and retry next step
         inject.check("serve.decode")
-        order = sorted((s for s in self.scheduler.active
-                        if s not in self._prefills),
-                       key=self.scheduler.admit_key)
-        # _grow may preempt later slots as reclaim victims — skip them
-        decoding = [s for s in order
-                    if self.scheduler.slots[s] is not None and self._grow(s)]
+        with obs.span("serve.grow"):
+            order = sorted((s for s in self.scheduler.active
+                            if s not in self._prefills),
+                           key=self.scheduler.admit_key)
+            # _grow may preempt later slots as reclaim victims — skip them
+            decoding = [s for s in order if self.scheduler.slots[s]
+                        is not None and self._grow(s)]
         if not decoding:
             return
-        toks = np.zeros((self.n_slots, 1), np.int32)
-        pos = np.full(self.n_slots, self.max_seq, np.int64)
-        for s in decoding:
-            toks[s, 0] = self.scheduler.slots[s].next_token
-            pos[s] = self.pos[s]
-        with obs.span("serve.decode_step"):
-            logits, self.cache = self._decode_batch(
-                self.params, self.cache, jnp.asarray(toks),
-                jnp.asarray(pos, jnp.int32), jnp.asarray(self.row_map))
+        with obs.span("serve.decode_inputs"):
+            toks = np.zeros((self.n_slots, 1), np.int32)
+            pos = np.full(self.n_slots, self.max_seq, np.int64)
+            for s in decoding:
+                toks[s, 0] = self.scheduler.slots[s].next_token
+                pos[s] = self.pos[s]
+            inputs = (jnp.asarray(toks), jnp.asarray(pos, jnp.int32),
+                      jnp.asarray(self.row_map))
+        # dispatch through the readback: the span ends when the step has
+        # run on the device, not when it was enqueued
+        with obs.span("serve.decode_step", {"batch": len(decoding)}
+                      if obs.active() else None):
+            logits, self.cache = self._decode_batch(self.params, self.cache,
+                                                    *inputs)
+            with obs.span("serve.decode_sync"):
+                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
         self.decode_steps += 1
-        nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1))
-        for s in decoding:
-            req = self.scheduler.slots[s]
-            tok = int(nxt[s])
-            req.out.append(tok)
-            req.next_token = tok
-            self.pos[s] += 1
-            self.generated += 1
-            if len(req.out) >= req.max_new \
-                    or self.pos[s] >= self._budget(req):
-                _obs_finish(req)
-                finished.append(self.scheduler.retire(s))
-                self._release(s)
+        with obs.span("serve.retire"):
+            for s in decoding:
+                req = self.scheduler.slots[s]
+                tok = int(nxt[s])
+                req.out.append(tok)
+                req.next_token = tok
+                self.pos[s] += 1
+                self.generated += 1
+                if len(req.out) >= req.max_new \
+                        or self.pos[s] >= self._budget(req):
+                    _obs_finish(req)
+                    finished.append(self.scheduler.retire(s))
+                    self._release(s)
         st = obs.state()
         if st is not None:
             st.metrics.histogram(

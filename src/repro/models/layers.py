@@ -82,6 +82,7 @@ def embed_specs() -> Params:
     return {"table": P(TP, FSDP)}
 
 
+@jax.named_scope("embed")
 def embed(params: Params, tokens: jax.Array, scale: bool = False) -> jax.Array:
     out = jnp.take(params["table"], tokens, axis=0)
     if scale:
@@ -151,6 +152,7 @@ def gather_pages(pool: jax.Array, row_map: jax.Array) -> jax.Array:
     return jnp.where(valid, rows, jnp.zeros((), pool.dtype))
 
 
+@jax.named_scope("attention")
 def attention(params: Params, cfg: ModelConfig, x: jax.Array, *,
               positions: jax.Array, tp: int, impl: str,
               window: int = 0, cache: Params | None = None,
@@ -189,6 +191,7 @@ def attention(params: Params, cfg: ModelConfig, x: jax.Array, *,
         cpos = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (bsz,))
         b_idx = jnp.arange(bsz)[:, None]
 
+        @jax.named_scope("kv_write")
         def put(name, val):
             s = val.shape[1]
             if ring and s >= window:
@@ -350,6 +353,7 @@ def mlp_specs() -> Params:
             "w_down": P(TP, FSDP)}
 
 
+@jax.named_scope("mlp")
 def mlp(params: Params, x: jax.Array, gelu: bool = False) -> jax.Array:
     act = jax.nn.gelu if gelu else jax.nn.silu
     h = act(x @ params["w_gate"]) * (x @ params["w_up"])
@@ -405,6 +409,7 @@ def _dispatch_group(x2, logits, k, cap):
     return buf, flat_e, slot, keep, gates
 
 
+@jax.named_scope("moe")
 def moe(params: Params, cfg: ModelConfig, x: jax.Array, tp: int) -> jax.Array:
     """Grouped capacity-dispatch MoE (DESIGN.md §5, EXPERIMENTS.md §Perf).
 

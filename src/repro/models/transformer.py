@@ -170,14 +170,16 @@ def _run_layers(params, cfg: ModelConfig, x, *, positions, tp, impl,
             xs = ((loc, caches["local"]), (glo, caches["global"]))
         else:
             xs = (loc, glo)
-        x, ys = jax.lax.scan(pair, x, xs)
+        with jax.named_scope("layers"):
+            x, ys = jax.lax.scan(pair, x, xs)
         new_caches = ({"local": ys[0], "global": ys[1]} if decode else None)
     else:
         window = cfg.local_window
         body = make_body(window)
         xs = (params["layers"]["all"], caches["all"]) if decode \
             else params["layers"]["all"]
-        x, ys = jax.lax.scan(body, x, xs)
+        with jax.named_scope("layers"):
+            x, ys = jax.lax.scan(body, x, xs)
         new_caches = {"all": ys} if decode else None
     return x, new_caches
 
@@ -389,7 +391,8 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Params,
     x, new_cache = _run_layers(params, cfg, x, positions=positions, tp=tp,
                                impl=impl, caches=cache, cache_pos=pos,
                                row_map=row_map)
-    x = L.rms_norm(x, params["final_norm"], plus_one=cfg.sandwich_norm)
-    head = params.get("head", params["embed"])
-    logits = L.unembed(head, x, cfg.vocab, cap=cfg.final_softcap)
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(x, params["final_norm"], plus_one=cfg.sandwich_norm)
+        head = params.get("head", params["embed"])
+        logits = L.unembed(head, x, cfg.vocab, cap=cfg.final_softcap)
     return logits, new_cache

@@ -1,14 +1,20 @@
 """Unified tracing + metrics layer (DESIGN.md §13).
 
 Off by default.  One module-level singleton (:func:`state`) guards every
-instrumentation site in serving, co-design, and the tuner:
+instrumentation site in serving, co-design, and the tuner.  Spans have two
+sinks, each on by itself:
 
-  * **disabled** (the default) — :func:`state` returns ``None`` and
-    :func:`span`/:func:`instant` hand back a shared no-op, so an
-    uninstrumented run pays one global read + ``is not None`` per site and
-    allocates nothing (call sites that build ``args`` dicts or touch
-    metrics must sit behind an ``if st is not None`` guard — the decode hot
-    path's zero-allocation contract, gated by ``benchmarks/bench_obs.py``);
+  * **disabled** (the default) — :func:`state` returns ``None`` and, with
+    no profiler session recording, :func:`span`/:func:`instant` hand back a
+    shared no-op, so an uninstrumented run pays one global read and one
+    profiler check per site and allocates nothing (call sites that build
+    ``args`` dicts must sit behind :func:`active`, and those that touch
+    metrics behind ``if st is not None`` — the decode hot path's
+    zero-allocation contract, gated by ``benchmarks/bench_obs.py``);
+  * **profiled** — while a JAX profiler session records, every span and
+    instant is also a ``jax.profiler.TraceAnnotation`` (its ``args`` the
+    event's stats), so the program's own spans lie on the device trace's
+    clock beside the ops they dispatch;
   * **enabled** (:func:`enable`) — spans land in a preallocated ring buffer
     (:mod:`repro.obs.trace`), instruments in a
     :class:`~repro.obs.metrics.MetricsRegistry`, and :func:`snapshot` /
@@ -22,6 +28,9 @@ Instrumentation idioms::
 
     with obs.span("serve.decode_step"):      # no-op CM when disabled
         ...
+    with obs.span("serve.commit",            # guard the args dict
+                  {"rid": rid} if obs.active() else None):
+        ...
     st = obs.state()
     if st is not None:                       # guard dict/metric work
         st.tracer.instant("req.retire", {"rid": rid})
@@ -32,14 +41,14 @@ from __future__ import annotations
 from .metrics import (DEFAULT_COUNT_EDGES, DEFAULT_TIME_EDGES, Counter,
                       Gauge, Histogram, MetricsRegistry, geometric_edges,
                       linear_edges)
-from .trace import NULL_SPAN, Tracer
+from .trace import NULL_SPAN, Annotated, Tracer, annotation, profiling
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "ObsState",
-    "Tracer", "DEFAULT_COUNT_EDGES", "DEFAULT_TIME_EDGES", "disable",
-    "enable", "enabled", "export_chrome_trace", "export_telemetry",
-    "geometric_edges", "instant", "linear_edges", "snapshot", "span",
-    "state",
+    "Tracer", "DEFAULT_COUNT_EDGES", "DEFAULT_TIME_EDGES", "active",
+    "disable", "enable", "enabled", "export_chrome_trace",
+    "export_telemetry", "geometric_edges", "instant", "linear_edges",
+    "profiling", "snapshot", "span", "state",
 ]
 
 
@@ -78,18 +87,34 @@ def state() -> ObsState | None:
     return _STATE
 
 
+def active() -> bool:
+    """Whether a span would be recorded anywhere: the guard for building
+    its ``args``."""
+    return _STATE is not None or profiling()
+
+
 def span(name: str, args: dict | None = None):
-    """A span context manager, or the shared no-op when disabled."""
+    """A span context manager: a ring-buffer span when enabled, a profiler
+    annotation while a profiler session records, both when both, and the
+    shared no-op when neither."""
     st = _STATE
+    if profiling():
+        ann = annotation(name, args)
+        return ann if st is None else Annotated(st.tracer.span(name, args),
+                                                ann)
     if st is None:
         return NULL_SPAN
     return st.tracer.span(name, args)
 
 
 def instant(name: str, args: dict | None = None) -> None:
+    """A zero-length event, in whichever sinks are on."""
     st = _STATE
     if st is not None:
         st.tracer.instant(name, args)
+    if profiling():
+        with annotation(name, args):
+            pass
 
 
 def snapshot() -> dict:

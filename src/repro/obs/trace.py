@@ -6,6 +6,11 @@ an *enabled* tracer stays cheap enough to leave on around jitted model
 steps.  When the buffer wraps, the oldest events are overwritten (``dropped``
 counts them); capacity is chosen at construction and never grows.
 
+While a profiler session records (``jax.profiler.start_trace`` or a
+remote capture), :mod:`repro.obs` also hands each span to the profiler as a
+``jax.profiler.TraceAnnotation`` (:func:`annotation`), so the program's
+spans share the device trace's clock; :func:`profiling` is that test.
+
 Export is Chrome trace-event JSON (the ``{"traceEvents": [...]}`` document
 both ``chrome://tracing`` and https://ui.perfetto.dev open directly):
 complete spans are ``"ph": "X"`` events with microsecond ``ts``/``dur``,
@@ -17,6 +22,8 @@ from __future__ import annotations
 
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 # event tuple layout: (ph, name, ts_us, dur_us, tid, depth, args)
 PH, NAME, TS, DUR, TID, DEPTH, ARGS = range(7)
@@ -36,6 +43,49 @@ class _NullSpan:
 
 
 NULL_SPAN = _NullSpan()
+
+
+def _profiling_probe():
+    """The cheapest test of whether a profiler session records: XLA's own
+    ``TraceMe`` flag (tens of nanoseconds a call), else, should that
+    symbol move, whether ``jax.profiler.start_trace`` holds a session."""
+    try:
+        from jax._src.lib import _profiler
+        return _profiler.TraceMe.is_enabled
+    except (ImportError, AttributeError):
+        pass
+    try:
+        from jax._src.profiler import _profile_state
+    except ImportError:
+        return lambda: False
+    return lambda: _profile_state.profile_session is not None
+
+
+profiling = _profiling_probe()
+
+
+def annotation(name: str, args: dict | None = None):
+    """A profiler span: ``args`` become the event's stats."""
+    return TraceAnnotation(name, **args) if args else TraceAnnotation(name)
+
+
+class Annotated:
+    """A ring-buffer span that is also a profiler annotation (both sinks
+    on); the annotation encloses the span."""
+
+    __slots__ = ("_span", "_ann")
+
+    def __init__(self, span, ann):
+        self._span, self._ann = span, ann
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self._span.__enter__()
+
+    def __exit__(self, *exc) -> bool:
+        self._span.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
 
 
 class _Span:

@@ -335,6 +335,140 @@ def test_disabled_serve_creates_no_events_or_metrics():
     assert len(st_.metrics) == 0
 
 
+# ---------------------------------------------------------------------------
+# Profiler sink: spans as TraceAnnotations on the device trace's clock
+# ---------------------------------------------------------------------------
+
+ENGINE_SPANS = {"serve.step", "serve.admit", "serve.prefill_init",
+                "serve.swap_in", "serve.prefill_tick", "serve.prefill_chunk",
+                "serve.commit", "serve.decode_tick", "serve.grow",
+                "serve.decode_inputs", "serve.decode_step",
+                "serve.decode_sync", "serve.retire"}
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a JAX profiler session (Python tracer off, as the
+    benchmark traces); returns the host events as (name, start_ns, end_ns,
+    stats)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (found,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                         recursive=True)
+    return [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+            for p in ProfileData.from_file(found).planes
+            if p.name.startswith("/host:") for ln in p.lines
+            for e in ln.events]
+
+
+def _inside(inner, outer):
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+
+def test_profiled_engine_spans_nest_and_carry_request_ids(tmp_path):
+    from repro.launch.serve import serve_requests
+    cfg, params = _family("qwen3-8b")
+    kw = dict(slots=3, paged=True, page_size=4, n_pages=8, prefill_chunk=4)
+    serve_requests(cfg, params, _mixed(cfg), **kw)           # compile first
+    out = {}
+    events = _profiled(tmp_path, lambda: out.update(
+        stats=serve_requests(cfg, params, _mixed(cfg), **kw)[1]))
+    assert obs.state() is None                 # the ring stayed off
+    assert out["stats"]["preemptions"] > 0     # swap_in is exercised
+    spans = [e for e in events if e[0].startswith("serve.")]
+    assert {e[0] for e in spans} == ENGINE_SPANS
+
+    def of(name):
+        return [e for e in spans if e[0] == name]
+
+    steps, ticks, decodes = of("serve.step"), of("serve.decode_tick"), \
+        of("serve.decode_step")
+    for sync in of("serve.decode_sync"):
+        dec = next(d for d in decodes if _inside(sync, d))
+        tick = next(t for t in ticks if _inside(dec, t))
+        assert any(_inside(tick, s) for s in steps)
+    for child in ("serve.prefill_init", "serve.swap_in"):
+        assert all(any(_inside(e, a) for a in of("serve.admit"))
+                   for e in of(child))
+    for child in ("serve.prefill_chunk", "serve.commit"):
+        assert all(any(_inside(e, a) for a in of("serve.prefill_tick"))
+                   for e in of(child))
+    for child in ("serve.grow", "serve.decode_inputs", "serve.retire"):
+        assert all(any(_inside(e, a) for a in ticks) for e in of(child))
+    # spans of one request share its id; a decode step names its batch
+    rids = {r.rid for r in _mixed(cfg)}
+    for name in ("serve.prefill_init", "serve.swap_in",
+                 "serve.prefill_chunk", "serve.commit"):
+        assert all(e[3]["rid"] in rids for e in of(name)), name
+    assert all(e[3]["tokens"] > 0 for e in of("serve.prefill_chunk"))
+    assert all(e[3]["rows"] > 0 for e in of("serve.commit"))
+    assert all(1 <= e[3]["batch"] <= 3 for e in decodes)
+    # the session is over: the disabled path is the shared no-op again
+    assert obs.span("serve.step") is obs.NULL_SPAN
+
+
+def test_profiler_and_ring_record_together(tmp_path):
+    st_ = obs.enable(capacity=16)
+
+    def work():
+        with obs.span("outer", {"k": 1}):
+            obs.instant("mark", {"j": 2})
+
+    events = _profiled(tmp_path, work)
+    outer = next(e for e in events if e[0] == "outer")
+    mark = next(e for e in events if e[0] == "mark")
+    assert outer[3] == {"k": 1} and mark[3] == {"j": 2}
+    assert _inside(mark, outer) and mark[2] - mark[1] < outer[2] - outer[1]
+    assert [e[NAME] for e in st_.tracer.events()] == ["mark", "outer"]
+    assert not obs.profiling()
+    obs.disable()
+    assert obs.span("outer", {"k": 1}) is obs.NULL_SPAN
+    assert not obs.active()
+
+
+@pytest.mark.parametrize("arch,scopes", [
+    ("granite-moe-3b-a800m", {"moe"}),
+    ("qwen3-8b", {"mlp"}),
+])
+def test_paged_programs_carry_named_scopes_and_names(arch, scopes):
+    """The batched decode and the chunked prefill are two programs, both
+    named ``decode_step...``; their ops name the model's layers."""
+    import re
+
+    import jax.numpy as jnp
+
+    from repro.launch.serve import _paged_jitted_steps, _paged_prefill_step
+    from repro.models import family_module
+    cfg, params = _family(arch)
+    mod = family_module(cfg)
+    slots, max_seq = 2, 16
+    cache = mod.init_paged_cache(cfg, slots, slots * max_seq, max_seq, 1)
+    decode, _, _ = _paged_jitted_steps(cfg, 1, "xla")
+    i32 = functools.partial(jnp.zeros, dtype=jnp.int32)
+    hlo = decode.lower(params, cache, i32((slots, 1)), i32((slots,)),
+                       i32((slots, max_seq))).compile().as_text()
+    prefill = _paged_prefill_step(cfg, 1, "xla").lower(
+        params, mod.init_prefill_cache(cfg, 1, 8, 1), i32((1, 4)),
+        i32((1,))).compile().as_text()
+    assert hlo.startswith("HloModule jit_decode_step_batch")
+    assert prefill.startswith("HloModule jit_decode_step_prefill")
+    found = {part for name in re.findall(r'op_name="([^"]*)"', hlo)
+             for part in name.split("/")}
+    assert {"layers", "attention", "kv_write", "lm_head", "embed"} | scopes \
+        <= found
+    assert not ({"moe", "mlp"} - scopes) & found
+
+
 def test_serve_stats_latency_summaries_match_percentiles():
     from repro.launch.serve import serve_requests
     cfg, params = _family("qwen3-8b")
