@@ -71,7 +71,7 @@ R_NO_INTROSPECTION = rule(
 #: Primitives that synchronize with the host when hit inside a program.
 CALLBACK_PRIMS = frozenset({
     "pure_callback", "io_callback", "debug_callback", "callback",
-    "host_callback_call", "outside_call",
+    "host_callback_call", "outside_call", "debug_print",
 })
 
 _BIG_CONST_BYTES = 1 << 20      # 1 MiB: above this, closure capture is
@@ -118,7 +118,6 @@ def audit_program(fn, *example_args, donate_argnums: tuple[int, ...] = (),
     closed = jax.make_jaxpr(fn)(*example_args)
     out: list[Finding] = []
 
-    donated_flat: list[bool] = []
     for eqn in _iter_eqns(closed.jaxpr):
         name = eqn.primitive.name
         if name in CALLBACK_PRIMS:
@@ -126,9 +125,12 @@ def audit_program(fn, *example_args, donate_argnums: tuple[int, ...] = (),
             out.append(Finding("error", R_HOST_CALLBACK, site,
                                f"primitive {name!r} ({cb}) synchronizes "
                                f"with the host every invocation"))
-        if name == "pjit" and "donated_invars" in eqn.params \
-                and not donated_flat:
-            donated_flat = list(eqn.params["donated_invars"])
+    # a jitted ``fn`` traces to one call (``jit``; ``pjit`` before jax 0.7)
+    # whose params say which arguments it donates
+    donated_flat: list[bool] = []
+    eqns = closed.jaxpr.eqns
+    if len(eqns) == 1 and eqns[0].primitive.name in ("jit", "pjit"):
+        donated_flat = list(eqns[0].params.get("donated_invars", ()))
 
     for var, const in zip(closed.jaxpr.constvars, closed.consts):
         aval = var.aval
@@ -304,13 +306,14 @@ def audit_hot_paths(cfg, *, slots: int = 2, max_seq: int = 16,
     # -- paged decode (page-table KV) ----------------------------------------
     pdecode, _, _ = _paged_jitted_steps(cfg, 1, "xla")
     n_pages = -(-max_seq // page_size) * slots
-    pcache = mod.init_paged_cache(cfg, slots, n_pages * page_size,
-                                  max_seq, 1)
     row_map = np.full((slots, max_seq), -1, np.int32)
     row_map[:, :page_size] = np.arange(
         slots * page_size, dtype=np.int32).reshape(slots, page_size)
 
     def paged_tick(t):
+        # a cache of its own each tick: the program consumes (donates) it
+        pcache = mod.init_paged_cache(cfg, slots, n_pages * page_size,
+                                      max_seq, 1)
         return (params, pcache, jnp.asarray(toks + t),
                 jnp.asarray(np.full(slots, 1 + t), jnp.int32),
                 jnp.asarray(row_map))

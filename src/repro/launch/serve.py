@@ -527,6 +527,11 @@ class ServeEngine:
         return sorted(done, key=lambda r: r.rid)
 
 
+# the cache's position in the decode step's arguments
+# (params, cache, tokens, pos[, row_map])
+CACHE_ARG = 1
+
+
 def _named_program(step, name: str):
     """``step`` under the function name ``name``, which jax.jit makes the
     program's name (``jit_<name>``): the paged engine's batched decode and
@@ -545,33 +550,42 @@ def _paged_jitted_steps(cfg, tp: int, impl: str):
     engine instances like :func:`_jitted_steps`: the batched decode
     (``jit_decode_step_batch``), the commit write and the cache's slot
     axes.  jax.jit additionally keys the decode program on the page-table
-    width and the write program on the packed prompt length."""
+    width and the write program on the packed prompt length.  The decode
+    program owns the cache it is given (donated, updated in place): a
+    caller keeps only the cache it returns (DESIGN.md §12)."""
     mod = family_module(cfg)
     decode = jax.jit(_named_program(make_decode_step(cfg, tp=tp, impl=impl),
-                                    "decode_step_batch"))
+                                    "decode_step_batch"),
+                     donate_argnums=CACHE_ARG)
     axes = mod.paged_slot_axes(cfg)
+    return decode, jax.jit(_paged_write_slot(axes)), axes
 
+
+def _paged_write_slot(axes):
     def write_slot(cache, packed, slot, prows):
         """Scatter one finished batch-1 prefill: pool leaves land at the
-        slot's page-table rows ``prows``, per-slot leaves replace the slot
-        slice wholesale (the KV/state reset of DESIGN.md §11)."""
+        slot's page-table rows ``prows`` (each row shaped as the pool's),
+        per-slot leaves replace the slot slice wholesale (the KV/state
+        reset of DESIGN.md §11)."""
         def wr(c, pc, ax):
             if ax == "pool":
                 rows = jax.lax.index_in_dim(pc, 0, 1, keepdims=False)
+                rows = rows.reshape(rows.shape[:2] + c.shape[2:])
                 return c.at[:, prows].set(rows.astype(c.dtype), mode="drop")
             return jax.lax.dynamic_update_index_in_dim(
                 c, jax.lax.index_in_dim(pc, 0, ax, keepdims=False), slot, ax)
         return jax.tree_util.tree_map(wr, cache, packed, axes)
-
-    return decode, jax.jit(write_slot), axes
+    return write_slot
 
 
 @functools.lru_cache(maxsize=None)
 def _paged_prefill_step(cfg, tp: int, impl: str):
     """The chunked prefill (``jit_decode_step_prefill``): the same step as
-    the batched decode, on a private batch-1 dense cache."""
+    the batched decode, on a private batch-1 dense cache, which it likewise
+    owns (donated)."""
     return jax.jit(_named_program(make_decode_step(cfg, tp=tp, impl=impl),
-                                  "decode_step_prefill"))
+                                  "decode_step_prefill"),
+                   donate_argnums=CACHE_ARG)
 
 
 @functools.lru_cache(maxsize=None)
@@ -587,21 +601,13 @@ def _mesh_paged_jitted_steps(cfg, tp: int, impl: str, mesh):
     c_sh = named(mod.paged_cache_specs(cfg), mesh)
     pf_sh = named(_prefill_cache_specs(mod, cfg), mesh)
 
-    def write_slot(cache, packed, slot, prows):
-        def wr(c, pc, ax):
-            if ax == "pool":
-                rows = jax.lax.index_in_dim(pc, 0, 1, keepdims=False)
-                return c.at[:, prows].set(rows.astype(c.dtype), mode="drop")
-            return jax.lax.dynamic_update_index_in_dim(
-                c, jax.lax.index_in_dim(pc, 0, ax, keepdims=False), slot, ax)
-        return jax.tree_util.tree_map(wr, cache, packed, axes)
-
     step = make_decode_step(cfg, tp=tp, impl=impl)
     decode = jax.jit(_named_program(step, "decode_step_batch"),
-                     out_shardings=(None, c_sh))
+                     out_shardings=(None, c_sh), donate_argnums=CACHE_ARG)
     prefill = jax.jit(_named_program(step, "decode_step_prefill"),
-                      out_shardings=(None, pf_sh))
-    return decode, prefill, jax.jit(write_slot, out_shardings=c_sh)
+                      out_shardings=(None, pf_sh), donate_argnums=CACHE_ARG)
+    return decode, prefill, jax.jit(_paged_write_slot(axes),
+                                    out_shardings=c_sh)
 
 
 @dataclasses.dataclass

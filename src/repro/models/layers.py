@@ -141,26 +141,41 @@ def _split_heads(x, n, hd):
     return x.reshape(b, s, n, hd)
 
 
-def gather_pages(pool: jax.Array, row_map: jax.Array) -> jax.Array:
+def gather_pages(pool: jax.Array, row_map: jax.Array,
+                 layer: jax.Array | None = None) -> jax.Array:
     """Page-table gather: physical pool ``(R, ...)`` -> per-slot dense view
     ``(B, L, ...)`` where row ``i`` of slot ``b`` is ``pool[row_map[b, i]]``.
     Unmapped rows (``-1``) read as zeros, so the view is bit-identical to
-    the dense ``(B, L, ...)`` cache layout the non-paged engine carries."""
+    the dense ``(B, L, ...)`` cache layout the non-paged engine carries.
+    With ``layer``, ``pool`` is the layer-stacked ``(n, R, ...)`` leaf and
+    the rows are read from ``pool[layer]`` in the one gather."""
     safe = jnp.where(row_map >= 0, row_map, 0)
-    rows = pool[safe]
-    valid = (row_map >= 0).reshape(row_map.shape + (1,) * (pool.ndim - 1))
+    rows = pool[safe] if layer is None else pool[layer, safe]
+    valid = (row_map >= 0).reshape(row_map.shape + (1,) * (rows.ndim - 2))
     return jnp.where(valid, rows, jnp.zeros((), pool.dtype))
+
+
+# A layer's K or V pool of at most this many bytes is read into a buffer of
+# its own before the row gather: the v5e places such a buffer in its 128
+# MiB of on-chip memory (a layer's 40 and 80 MiB were placed there, 120 MiB
+# was not), and the gather from there runs about twice as fast as one from
+# HBM.  A larger pool is gathered straight from the stacked leaf: staged in
+# HBM, the copy costs more than it saves (PERF.md §6).
+STAGE_POOL_BYTES = 96 << 20
 
 
 @jax.named_scope("attention")
 def attention(params: Params, cfg: ModelConfig, x: jax.Array, *,
               positions: jax.Array, tp: int, impl: str,
               window: int = 0, cache: Params | None = None,
+              layer: jax.Array | None = None,
               cache_pos: jax.Array | None = None,
               row_map: jax.Array | None = None):
-    """Returns (out, new_cache).  cache = {'k','v'}: (B, S_max, KV, hd) —
-    or, when ``row_map`` is given and the leaves are 3-D, a paged physical
-    pool (R, KV, hd) indexed through the (B, L) page table (DESIGN.md §12)."""
+    """Returns (out, new_cache).  cache = {'k','v'}, stacked over the layers
+    and updated in place at index ``layer``: (n, B, S_max, *row) — or, when
+    ``row_map`` is given and the leaves are 4-D, a paged physical pool
+    (n, R, *row) indexed through the (B, L) page table (DESIGN.md §12); a
+    K/V row is :func:`kv_row_shape`."""
     pd = cfg.padded(tp)
     h, kv, hd = pd.n_heads, pd.n_kv_heads, cfg.head_dim
     rep = max(1, kv // max(1, cfg.n_kv_heads))  # kv replication factor
@@ -181,11 +196,14 @@ def attention(params: Params, cfg: ModelConfig, x: jax.Array, *,
         # ``cache_pos`` is per-slot — a (B,) vector of absolute write
         # positions (a scalar broadcasts) — so co-scheduled requests at
         # different depths each write and mask at their own position
-        # (DESIGN.md §11).  A 3-D cache leaf is a paged pool: writes and
-        # reads route through ``row_map``; ring leaves stay dense (a ring is
-        # already O(window) per slot), so one model can mix both.
-        paged = row_map is not None and cache["k"].ndim == 3
-        cache_len = row_map.shape[1] if paged else cache["k"].shape[1]
+        # (DESIGN.md §11).  A (layers, R, ...) leaf is a paged pool: writes
+        # and reads route through ``row_map``; ring leaves stay dense (a
+        # ring is already O(window) per slot), so one model can mix both.
+        # Every leaf holds all the layers: the new rows are scattered into
+        # the stacked leaf itself, and the layer's rows read from it, so no
+        # step writes a layer's cache back into the stack.
+        paged = row_map is not None and cache["k"].ndim == 4
+        cache_len = row_map.shape[1] if paged else cache["k"].shape[2]
         ring = not paged and window > 0 and cache_len == window
         bsz, sq = q.shape[0], q.shape[1]
         cpos = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (bsz,))
@@ -193,10 +211,14 @@ def attention(params: Params, cfg: ModelConfig, x: jax.Array, *,
 
         @jax.named_scope("kv_write")
         def put(name, val):
+            leaf = cache[name]
             s = val.shape[1]
             if ring and s >= window:
                 val = val[:, s - window:]   # a full wrap keeps only the tail
             kept = val.shape[1]
+            # each row as the leaf lays it out (kv_row_shape)
+            val = val.reshape((bsz, kept) + leaf.shape[2 if paged else 3:]
+                              ).astype(leaf.dtype)
             rows = cpos[:, None] + (s - kept) + jnp.arange(kept)
             if paged:
                 # logical row -> physical pool row via the slot's page
@@ -204,25 +226,34 @@ def attention(params: Params, cfg: ModelConfig, x: jax.Array, *,
                 # (-1) entries redirect to index R: negative indices WRAP
                 # under mode="drop" (only >= size is out of bounds), so -1
                 # would silently stomp the last pool row
-                pool_rows = cache[name].shape[0]
                 safe = jnp.clip(rows, 0, cache_len - 1)
                 prow = jnp.take_along_axis(row_map, safe, axis=1)
                 prow = jnp.where((rows < cache_len) & (prow >= 0), prow,
-                                 pool_rows)
-                return cache[name].at[prow].set(
-                    val.astype(cache[name].dtype), mode="drop")
+                                 leaf.shape[1])
+                return leaf.at[layer, prow].set(val, mode="drop")
             if ring:
                 rows = rows % window
             # out-of-range rows (a retired slot parked past its budget) are
             # dropped rather than clamped onto the last row
-            return cache[name].at[b_idx, rows].set(
-                val.astype(cache[name].dtype), mode="drop")
+            return leaf.at[layer, b_idx, rows].set(val, mode="drop")
 
-        def full(name):
-            """Dense (B, L, ...) view of the updated cache leaf."""
-            if paged:
-                return gather_pages(new_cache[name], row_map)
-            return new_cache[name]
+        def full(name, like):
+            """Dense (B, L, ...) view of the layer's updated cache, its rows
+            shaped as ``like``'s (a pool row may group heads: see
+            :func:`kv_row_shape`)."""
+            leaf = new_cache[name]
+            if not paged:
+                rows = leaf[layer]
+            elif (math.prod(leaf.shape[1:]) * leaf.dtype.itemsize
+                  <= STAGE_POOL_BYTES):
+                # the layer's pool, read whole into a buffer of its own (the
+                # barrier keeps XLA from fusing the slice into the gather);
+                # the buffer is only read, nothing is written back
+                rows = gather_pages(
+                    jax.lax.optimization_barrier(leaf[layer]), row_map)
+            else:
+                rows = gather_pages(leaf, row_map, layer)
+            return rows.reshape(rows.shape[:2] + like.shape[2:])
 
         if "k_scale" in cache:   # int8 KV: per-(token, head) scales
             def quant(z):
@@ -235,11 +266,13 @@ def attention(params: Params, cfg: ModelConfig, x: jax.Array, *,
             new_cache = {"k": put("k", kq), "v": put("v", vq),
                          "k_scale": put("k_scale", ks),
                          "v_scale": put("v_scale", vs)}
-            ck = full("k").astype(jnp.float32) * full("k_scale")[..., None]
-            cv = full("v").astype(jnp.float32) * full("v_scale")[..., None]
+            ck = (full("k", kq).astype(jnp.float32)
+                  * full("k_scale", ks)[..., None])
+            cv = (full("v", vq).astype(jnp.float32)
+                  * full("v_scale", vs)[..., None])
         else:
             new_cache = {"k": put("k", k), "v": put("v", v)}
-            ck, cv = full("k"), full("v")
+            ck, cv = full("k", k), full("v", v)
 
         last = cpos + sq - 1                                 # (B,)
         if ring:
@@ -289,28 +322,46 @@ def _decode_attention(q, ck, cv, cfg: ModelConfig, last_pos, window: int,
 
 def init_kv_cache(cfg: ModelConfig, batch: int, max_seq: int, tp: int,
                   dtype=jnp.bfloat16) -> Params:
-    pd = cfg.padded(tp)
-    shape = (batch, max_seq, pd.n_kv_heads, cfg.head_dim)
+    """Dense per-slot KV: ``(batch, max_seq)`` rows, each K and V row shaped
+    by :func:`kv_row_shape`, each scale row one value a kv head."""
+    shape = (batch, max_seq) + kv_row_shape(cfg, tp)
     if cfg.kv_int8:
+        kv = cfg.padded(tp).n_kv_heads
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:3], jnp.float32),
-                "v_scale": jnp.zeros(shape[:3], jnp.float32)}
+                "k_scale": jnp.zeros((batch, max_seq, kv), jnp.float32),
+                "v_scale": jnp.zeros((batch, max_seq, kv), jnp.float32)}
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
+def kv_row_shape(cfg: ModelConfig, tp: int) -> tuple[int, int]:
+    """One row of a K or V cache leaf, pooled or dense: ``(KV / g, g * hd)``,
+    ``g`` kv heads side by side, as many as fill the 128 lanes of a TPU
+    vector register while every tensor-parallel shard keeps whole groups.
+    A row ``(KV, 64)`` is narrower than the lanes: the TPU then lays the
+    pool out with its rows minor, and the decode's row gather and scatter
+    need a whole-pool relayout every step.  ``(KV / 2, 128)`` keeps the
+    rows major and the pool compact; heads of 128 or more stay as they
+    are."""
+    kv, hd = cfg.padded(tp).n_kv_heads, cfg.head_dim
+    g = math.gcd(128 // hd, kv // tp) if 128 % hd == 0 else 1
+    return kv // g, g * hd
 
 
 def init_paged_kv_pool(cfg: ModelConfig, rows: int, tp: int,
                        dtype=jnp.bfloat16) -> Params:
     """Physical KV pool of ``rows`` page-resident rows shared by every slot
     (DESIGN.md §12).  Same leaf set as :func:`init_kv_cache` minus the slot
-    axis: the engine's page table supplies the slot -> row indirection."""
-    pd = cfg.padded(tp)
-    shape = (rows, pd.n_kv_heads, cfg.head_dim)
+    axis: the engine's page table supplies the slot -> row indirection.
+    K and V rows are shaped by :func:`kv_row_shape`; the scales keep one
+    value a kv head."""
+    shape = (rows,) + kv_row_shape(cfg, tp)
     if cfg.kv_int8:
+        kv = cfg.padded(tp).n_kv_heads
         return {"k": jnp.zeros(shape, jnp.int8),
                 "v": jnp.zeros(shape, jnp.int8),
-                "k_scale": jnp.zeros(shape[:2], jnp.float32),
-                "v_scale": jnp.zeros(shape[:2], jnp.float32)}
+                "k_scale": jnp.zeros((rows, kv), jnp.float32),
+                "v_scale": jnp.zeros((rows, kv), jnp.float32)}
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -324,10 +375,10 @@ def kv_cache_specs(cfg: ModelConfig | None = None) -> Params:
 
 
 def paged_kv_pool_specs(cfg: ModelConfig | None = None) -> Params:
-    """Shardings for :func:`init_paged_kv_pool` leaves (rows, kv_heads, hd):
-    kv-heads shard over TP like the dense cache; the physical-row axis stays
-    replicated — rows are addressed by the host-side page table, which must
-    see every row on every shard."""
+    """Shardings for :func:`init_paged_kv_pool` leaves (rows, head groups,
+    lanes): kv-heads shard over TP like the dense cache, whole groups to a
+    shard; the physical-row axis stays replicated — rows are addressed by
+    the host-side page table, which must see every row on every shard."""
     base = {"k": P(None, TP, None), "v": P(None, TP, None)}
     if cfg is not None and cfg.kv_int8:
         base["k_scale"] = P(None, TP)

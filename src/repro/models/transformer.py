@@ -60,12 +60,13 @@ def _block_specs(cfg: ModelConfig) -> Params:
 
 
 def _block(p: Params, cfg: ModelConfig, x, *, positions, tp, impl, window,
-           cache=None, cache_pos=None, row_map=None):
+           cache=None, layer=None, cache_pos=None, row_map=None):
     plus_one = cfg.sandwich_norm  # gemma-style (1+w) norms
     h = L.rms_norm(x, p["ln_attn"], plus_one=plus_one)
     attn_out, new_cache = L.attention(
         p["attn"], cfg, h, positions=positions, tp=tp, impl=impl,
-        window=window, cache=cache, cache_pos=cache_pos, row_map=row_map)
+        window=window, cache=cache, layer=layer, cache_pos=cache_pos,
+        row_map=row_map)
     if cfg.sandwich_norm:
         attn_out = L.rms_norm(attn_out, p["ln_attn_post"], plus_one=True)
     x = x + attn_out
@@ -132,19 +133,26 @@ def specs(cfg: ModelConfig) -> Params:
 def _run_layers(params, cfg: ModelConfig, x, *, positions, tp, impl,
                 caches=None, cache_pos=None, row_map=None):
     """Scan the block stack; returns (x, new_caches).  ``row_map`` is the
-    per-slot page table, shared by every paged layer (closure, not scanned)."""
+    per-slot page table, shared by every paged layer (closure, not scanned).
+
+    In decode the stacked caches ride in the scan's carry beside ``x`` and
+    the layer index is scanned with the weights: each layer scatters its
+    new rows into, and reads its rows from, the stacked leaves in place.
+    Scanned as ``xs``/``ys`` instead, every layer would slice its whole
+    cache out and write it back into a fresh stack."""
     decode = caches is not None
 
     def make_body(window):
         def body(carry, xs):
-            x = carry
             if decode:
-                lp, cache = xs
-                x, nc = _block(lp, cfg, x, positions=positions, tp=tp,
-                               impl=impl, window=window, cache=cache,
-                               cache_pos=cache_pos, row_map=row_map)
-                return x, nc
-            x, _ = _block(xs, cfg, x, positions=positions, tp=tp,
+                x, cache = carry
+                lp, i = xs
+                x, cache = _block(lp, cfg, x, positions=positions, tp=tp,
+                                  impl=impl, window=window, cache=cache,
+                                  layer=i, cache_pos=cache_pos,
+                                  row_map=row_map)
+                return (x, cache), None
+            x, _ = _block(xs, cfg, carry, positions=positions, tp=tp,
                           impl=impl, window=window)
             return x, None
         if cfg.remat and not decode:
@@ -156,32 +164,34 @@ def _run_layers(params, cfg: ModelConfig, x, *, positions, tp, impl,
         body_l = make_body(cfg.local_window)
         body_g = make_body(0)
 
-        def pair(x, xs):
+        def pair(carry, xs):
             if decode:
-                (lpl, cl), (lpg, cg) = xs
-                x, ncl = body_l(x, (lpl, cl))
-                x, ncg = body_g(x, (lpg, cg))
-                return x, (ncl, ncg)
+                x, cl, cg = carry
+                lpl, lpg, i = xs
+                (x, cl), _ = body_l((x, cl), (lpl, i))
+                (x, cg), _ = body_g((x, cg), (lpg, i))
+                return (x, cl, cg), None
             lpl, lpg = xs
-            x, _ = body_l(x, lpl)
+            x, _ = body_l(carry, lpl)
             x, _ = body_g(x, lpg)
             return x, None
+        with jax.named_scope("layers"):
+            if decode:
+                (x, cl, cg), _ = jax.lax.scan(
+                    pair, (x, caches["local"], caches["global"]),
+                    (loc, glo, jnp.arange(_n_groups(cfg))))
+                return x, {"local": cl, "global": cg}
+            x, _ = jax.lax.scan(pair, x, (loc, glo))
+        return x, None
+    body = make_body(cfg.local_window)
+    with jax.named_scope("layers"):
         if decode:
-            xs = ((loc, caches["local"]), (glo, caches["global"]))
-        else:
-            xs = (loc, glo)
-        with jax.named_scope("layers"):
-            x, ys = jax.lax.scan(pair, x, xs)
-        new_caches = ({"local": ys[0], "global": ys[1]} if decode else None)
-    else:
-        window = cfg.local_window
-        body = make_body(window)
-        xs = (params["layers"]["all"], caches["all"]) if decode \
-            else params["layers"]["all"]
-        with jax.named_scope("layers"):
-            x, ys = jax.lax.scan(body, x, xs)
-        new_caches = {"all": ys} if decode else None
-    return x, new_caches
+            (x, cache), _ = jax.lax.scan(
+                body, (x, caches["all"]),
+                (params["layers"]["all"], jnp.arange(cfg.n_layers)))
+            return x, {"all": cache}
+        x, _ = jax.lax.scan(body, x, params["layers"]["all"])
+    return x, None
 
 
 def _embed_inputs(params, cfg: ModelConfig, inputs: Params) -> jax.Array:

@@ -177,10 +177,10 @@ def specs(cfg: ModelConfig) -> Params:
 
 
 def _shared_attn(shared: Params, cfg: ModelConfig, x, *, positions, tp, impl,
-                 cache=None, cache_pos=None, row_map=None):
+                 cache=None, layer=None, cache_pos=None, row_map=None):
     h = L.rms_norm(x, shared["ln_attn"])
     att, new_cache = L.attention(shared["attn"], cfg, h, positions=positions,
-                                 tp=tp, impl=impl, cache=cache,
+                                 tp=tp, impl=impl, cache=cache, layer=layer,
                                  cache_pos=cache_pos, row_map=row_map)
     x = x + att
     x = x + L.mlp(shared["mlp"], L.rms_norm(x, shared["ln_mlp"]))
@@ -337,17 +337,21 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *,
         x, ns = _mamba_block(lp, cfg, x, tp, impl, state=st)
         return x, ns
 
-    def group(x, xs):
-        gp, gconv, gssd, gattn = xs
+    # the stacked attention KV rides in the carry and is updated in place
+    # at the group's index (as in the transformer's layer scan)
+    def group(carry, xs):
+        x, attn = carry
+        gp, gconv, gssd, g = xs
         x, ns = jax.lax.scan(inner, x, (gp, {"conv": gconv, "ssd": gssd}))
-        x, nattn = _shared_attn(shared, cfg, x, positions=positions, tp=tp,
-                                impl=impl, cache=gattn, cache_pos=pos,
-                                row_map=row_map)
-        return x, (ns["conv"], ns["ssd"], nattn)
+        x, attn = _shared_attn(shared, cfg, x, positions=positions, tp=tp,
+                               impl=impl, cache=attn, layer=g, cache_pos=pos,
+                               row_map=row_map)
+        return (x, attn), (ns["conv"], ns["ssd"])
 
-    x, (nconv, nssd, nattn) = jax.lax.scan(
-        group, x, (params["layers"], cache["conv"], cache["ssd"],
-                   cache["attn"]))
+    (x, nattn), (nconv, nssd) = jax.lax.scan(
+        group, (x, cache["attn"]),
+        (params["layers"], cache["conv"], cache["ssd"],
+         jnp.arange(cache["conv"].shape[0])))
     x = L.rms_norm(x, params["final_norm"])
     logits = L.unembed(params["head"], x, cfg.vocab)
     return logits, {"conv": nconv, "ssd": nssd, "attn": nattn}

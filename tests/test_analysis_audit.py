@@ -128,3 +128,27 @@ def test_missed_donation_flagged_and_silenced_by_donation():
     assert "jaxpr/missed-donation" in _rules(warnings(got))
     assert audit_program(step, state, x, donate_argnums=(0,),
                          site="t") == []
+
+
+def test_paged_decode_donates_its_cache():
+    """The paged engine's decode program donates its cache, so the audit
+    finds no missed donation there; the same step jitted without donation
+    is flagged (the pool here is past the audit's size threshold)."""
+    from repro.launch.serve import _paged_jitted_steps
+    from repro.launch.steps import make_decode_step
+    from repro.models import family_module
+
+    cfg = reduced(get_config("qwen3-8b"))
+    mod = family_module(cfg)
+    slots, max_seq = 8, 128
+    args = (mod.init(cfg, jax.random.PRNGKey(0), 1),
+            mod.init_paged_cache(cfg, slots, slots * max_seq, max_seq, 1),
+            jnp.zeros((slots, 1), jnp.int32), jnp.zeros((slots,), jnp.int32),
+            jnp.zeros((slots, max_seq), jnp.int32))
+    decode, _, _ = _paged_jitted_steps(cfg, 1, "xla")
+    got = audit_program(decode, *args, site="paged_decode")
+    assert "jaxpr/missed-donation" not in _rules(got), \
+        [str(f) for f in got]
+    kept = audit_program(jax.jit(make_decode_step(cfg, tp=1)), *args,
+                         site="paged_decode")
+    assert "jaxpr/missed-donation" in _rules(warnings(kept))

@@ -330,3 +330,153 @@ def test_parked_lane_cannot_touch_the_pool():
                                    cache["all"][name][layer])
             assert touched <= {3}, \
                 f"layer {layer} {name}: parked lane wrote rows {touched}"
+
+
+# ---------------------------------------------------------------------------
+# the in-place decode == the per-layer form it replaced
+# ---------------------------------------------------------------------------
+
+def _per_layer_attention(params, cfg, x, *, positions, tp, impl, window=0,
+                         cache=None, layer=None, cache_pos=None,
+                         row_map=None):
+    """``layers.attention`` in its per-layer form: the layer's cache sliced
+    out of the stack with rows (KV, hd), the new rows scattered into the
+    slice (``pool.at[prow]``, ``cache.at[b, rows]``), the layer's rows read
+    from the slice with ``gather_pages``, and the slice written back."""
+    pd = cfg.padded(tp)
+    h, kv, hd = pd.n_heads, pd.n_kv_heads, cfg.head_dim
+    q = L._split_heads(x @ params["wq"], h, hd)
+    k = L._split_heads(x @ params["wk"], kv, hd)
+    v = L._split_heads(x @ params["wv"], kv, hd)
+    if cfg.qk_norm:
+        q = L.rms_norm(q, params["q_norm"])
+        k = L.rms_norm(k, params["k_norm"])
+    q = L.rope(q, positions, cfg.rope_theta)
+    k = L.rope(k, positions, cfg.rope_theta)
+    paged = row_map is not None and cache["k"].ndim == 4
+    lead = 1 if paged else 2                 # row axes of one layer's slice
+    cache_len = row_map.shape[1] if paged else cache["k"].shape[2]
+    ring = not paged and window > 0 and cache_len == window
+    bsz, sq = q.shape[:2]
+    cpos = jnp.broadcast_to(jnp.asarray(cache_pos, jnp.int32), (bsz,))
+    new_cache, views = {}, {}
+
+    def put(name, val):
+        stacked = cache[name]
+        sl = stacked[layer]
+        sl = sl.reshape(sl.shape[:lead] + val.shape[2:])
+        s = val.shape[1]
+        if ring and s >= window:
+            val = val[:, s - window:]
+        rows = cpos[:, None] + (s - val.shape[1]) + jnp.arange(val.shape[1])
+        val = val.astype(sl.dtype)
+        if paged:
+            prow = jnp.take_along_axis(
+                row_map, jnp.clip(rows, 0, cache_len - 1), axis=1)
+            prow = jnp.where((rows < cache_len) & (prow >= 0), prow,
+                             sl.shape[0])
+            sl = sl.at[prow].set(val, mode="drop")
+            views[name] = L.gather_pages(sl, row_map)
+        else:
+            if ring:
+                rows = rows % window
+            sl = sl.at[jnp.arange(bsz)[:, None], rows].set(val, mode="drop")
+            views[name] = sl
+        new_cache[name] = stacked.at[layer].set(
+            sl.reshape(stacked.shape[1:]))
+
+    if "k_scale" in cache:
+        def quant(z):
+            sc = jnp.max(jnp.abs(z.astype(jnp.float32)), axis=-1,
+                         keepdims=True) / 127.0 + 1e-12
+            return jnp.round(z.astype(jnp.float32) / sc
+                             ).astype(jnp.int8), sc[..., 0]
+        (kq, ks), (vq, vs) = quant(k), quant(v)
+        for name, val in (("k", kq), ("v", vq), ("k_scale", ks),
+                          ("v_scale", vs)):
+            put(name, val)
+        ck = views["k"].astype(jnp.float32) * views["k_scale"][..., None]
+        cv = views["v"].astype(jnp.float32) * views["v_scale"][..., None]
+    else:
+        put("k", k)
+        put("v", v)
+        ck, cv = views["k"], views["v"]
+    last = cpos + sq - 1
+    if ring:
+        slots = jnp.arange(cache_len)
+        kpos = last[:, None] - jax.lax.rem(
+            (last[:, None] - slots) % window + window, jnp.int32(window))
+        out = L._decode_attention(q, ck, cv, cfg, last, 0, kpos=kpos)
+    else:
+        out = L._decode_attention(q, ck, cv, cfg, last, window)
+    return out.reshape(bsz, sq, h * hd) @ params["wo"], new_cache
+
+
+def _filled(cache, rng):
+    """Every leaf filled with values of its dtype, so that reads of rows the
+    step does not write see something other than zeros."""
+    def fill(x):
+        if x.dtype == jnp.int8:
+            return jnp.asarray(rng.integers(-127, 128, x.shape), jnp.int8)
+        if x.dtype == jnp.float32:                   # int8 KV's scales
+            return jnp.asarray(rng.uniform(1e-3, 2e-2, x.shape), x.dtype)
+        return jnp.asarray(rng.standard_normal(x.shape), x.dtype)
+    return jax.tree_util.tree_map(fill, cache)
+
+
+# case -> (arch, config overrides, paged); "-unstaged" gathers straight
+# from the stacked pool, as layer pools past ``L.STAGE_POOL_BYTES`` do
+IN_PLACE_CASES = {
+    "pool": ("qwen3-8b", {}, True),
+    "pool-unstaged": ("qwen3-8b", {}, True),
+    "dense": ("qwen3-8b", {}, False),
+    "gemma2-local-global": ("gemma2-2b", {"local_window": 5, "n_layers": 4},
+                            True),
+    "int8-pool": ("qwen3-8b", {"kv_int8": True}, True),
+    "int8-pool-unstaged": ("qwen3-8b", {"kv_int8": True}, True),
+}
+
+
+@pytest.mark.parametrize("case", list(IN_PLACE_CASES))
+def test_in_place_decode_matches_per_layer_reference(case, monkeypatch):
+    """The decode step that scatters into and gathers from the stacked
+    cache in place gives the same logits and the same cache, bit for bit,
+    as the per-layer form: a multi-token chunk, then single-token steps
+    with the slots at different depths and one slot parked."""
+    arch, over, paged = IN_PLACE_CASES[case]
+    if case.endswith("-unstaged"):
+        monkeypatch.setattr(L, "STAGE_POOL_BYTES", 0)
+    cfg, mod, params = _family(arch, **over)
+    slots, max_seq, page = 3, 12, 4
+    rng = np.random.default_rng(11)
+    row_map = None
+    if paged:
+        n_pages = slots * max_seq // page + 1            # one spare page
+        cache = mod.init_paged_cache(cfg, slots, n_pages * page, max_seq, 1)
+        perm = rng.permutation(n_pages)
+        table = np.full((slots, max_seq), -1, np.int32)
+        for s in range(slots):
+            for j in range(max_seq // page - (s == 2)):  # slot 2: 2 pages
+                table[s, j * page:(j + 1) * page] = \
+                    perm[s * 3 + j] * page + np.arange(page)
+        row_map = jnp.asarray(table)
+    else:
+        cache = mod.init_cache(cfg, slots, max_seq, 1)
+    cache = _filled(cache, rng)
+    steps = [(3, [0, 4, 2])] + [(1, [3 + t, 7 + t, 5 + t]) for t in range(3)]
+    steps.append((1, [6, 10, max_seq]))                  # slot 2 parked
+    for width, pos in steps:
+        toks = jnp.asarray(rng.integers(0, cfg.vocab, (slots, width)),
+                           jnp.int32)
+        args = (params, cfg, cache, toks, jnp.asarray(pos, jnp.int32))
+        with monkeypatch.context() as m:
+            m.setattr(L, "attention", _per_layer_attention)
+            want_logits, want = mod.decode_step(*args, row_map=row_map)
+        got_logits, got = mod.decode_step(*args, row_map=row_map)
+        assert np.array_equal(np.asarray(got_logits),
+                              np.asarray(want_logits)), (case, pos)
+        for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                                jax.tree_util.tree_leaves(want)):
+            assert np.array_equal(np.asarray(a), np.asarray(b)), \
+                (case, pos, jax.tree_util.keystr(path))
+        cache = got

@@ -460,3 +460,65 @@ def test_paged_low_priority_is_not_starved():
     while eng.scheduler.has_work():            # drain; everyone completes
         finished.extend(eng.step())
     assert len(finished) == rid + 1
+
+
+# ---------------------------------------------------------------------------
+# paged engine: the decode and prefill programs own the cache they are given
+# ---------------------------------------------------------------------------
+
+def _record_caches(eng):
+    """Wrap the engine's batched decode and chunked prefill; returns the
+    list of caches the two programs are given."""
+    given = []
+    for attr in ("_decode_batch", "_decode"):
+        def record(params, cache, *rest, _step=getattr(eng, attr)):
+            given.append(cache)
+            return _step(params, cache, *rest)
+        setattr(eng, attr, record)
+    return given
+
+
+def _consumed(cache):
+    return all(x.is_deleted() for x in jax.tree_util.tree_leaves(cache))
+
+
+def test_decode_and_prefill_consume_the_cache_they_are_given():
+    """Both programs donate their cache: what the engine passed in is
+    deleted once they return, and the engine holds only what they gave
+    back."""
+    cfg, params = _family("qwen3-8b")
+    eng = PagedServeEngine(cfg, params, slots=2, max_seq=32, page_size=4,
+                           prefill_chunk=4)
+    given = _record_caches(eng)
+    eng.submit(Request(0, np.arange(1, 10, dtype=np.int32), 4))
+    (done,) = eng.run()
+    assert done.status == "OK" and len(done.out) == 4
+    assert len(given) == 3 + 3            # three prefill chunks, three steps
+    assert all(_consumed(c) for c in given)
+    assert not any(x.is_deleted() for x in jax.tree_util.tree_leaves(eng.cache))
+
+
+def test_swap_and_sanitize_run_across_donated_steps():
+    """Swap-out reads, and swap-in and commit write, the cache the last
+    donated step returned: under page pressure, with the sanitizer checking
+    the page table every tick, each request still serves exactly the
+    tokens a fresh single-request engine serves."""
+    cfg, params = _family("qwen3-8b")
+    rng = np.random.default_rng(3)
+    reqs = [Request(i, rng.integers(0, cfg.vocab, size=5).astype(np.int32),
+                    5, priority=(0 if i % 2 == 0 else 2)) for i in range(6)]
+    ref = _reference_outputs(cfg, params, reqs, max_seq=32)
+    eng = PagedServeEngine(cfg, params, slots=3, max_seq=32, page_size=2,
+                           n_pages=9, prefill_chunk=4, age_steps=0,
+                           sanitize=True)
+    given = _record_caches(eng)
+    for r in reqs:
+        eng.submit(Request(r.rid, r.prompt.copy(), r.max_new,
+                           priority=r.priority))
+    done = eng.run()
+    assert eng.preemptions > 0                 # swap-out and swap-in ran
+    assert sorted(r.rid for r in done) == [r.rid for r in reqs]
+    for r in done:
+        assert r.out == ref[r.rid], f"request {r.rid} diverged after " \
+            f"{r.preemptions} preemption(s)"
+    assert all(_consumed(c) for c in given)

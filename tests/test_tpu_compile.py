@@ -11,7 +11,10 @@ here instead of on the chip.  Nothing runs: results and times need the chip
 The topology is described inside a module fixture and nowhere else: only one
 process may load the TPU library, and it keeps it until it exits.
 """
+import dataclasses
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +28,7 @@ from repro.launch.mesh import make_mesh
 from repro.launch.serve import (_mesh_paged_jitted_steps, _paged_jitted_steps,
                                 _prefill_cache_specs)
 from repro.models import family_module
+from repro.models import layers as L
 
 HBM_BYTES = int(15.75 * 2**30)   # one v5e chip, as its compiler counts it
 
@@ -159,3 +163,66 @@ def test_qwen3_paged_decode_compiles_on_2x2_mesh(topo):
     weights = sum(x.size * x.dtype.itemsize
                   for x in jax.tree_util.tree_leaves(params))
     assert _fits(compiled) < weights / 2
+
+
+# the benchmark's three cells: (config, overrides, slots, rows a slot,
+# batch); the compile decodes a batch smaller than the cell's, over the
+# cell's whole pool, so that the page gather's dense view is not pool-sized
+# itself
+POOL_CELLS = {
+    "granite-moe-3b-a800m.chat": ("granite-moe-3b-a800m", {}, 32, 1280, 8),
+    "qwen3-8b-l12.chat": ("qwen3-8b", {"n_layers": 12}, 32, 1280, 8),
+    "qwen3-8b-l12.long-decode": ("qwen3-8b", {"n_layers": 12}, 5, 12288, 4),
+}
+# ops that copy or write back; one the size of the pool or of one layer's
+# pool copies, relays out or restacks the pool
+_WRITES = ("copy", "copy-start", "copy-done", "dynamic-update-slice")
+_BF16_OP = re.compile(r"%[\w.\-]+ = bf16\[([\d,]*)\]\{[^}]*\} ([\w\-]+)\(")
+
+
+@pytest.mark.parametrize("cell", list(POOL_CELLS))
+def test_paged_decode_updates_the_pool_in_place(one_chip, cell):
+    """The batched decode of each cell, at the cell's widths, depth and
+    pool, writes the stacked pool where it lies: no copy or update-slice
+    the size of the pool or of one layer's pool (in the loop or around
+    it), the pool aliased from argument to result.  The one pool-sized
+    read is the layer slice that the row gather reads from, in the chat
+    cells, whose layer pools are staged (``layers.STAGE_POOL_BYTES``): one
+    a K or V leaf, and temporaries under two layers' pool.  Long-decode's
+    larger layer pools are gathered straight from the stacked leaves: no
+    slice, and temporaries under one layer's pool."""
+    arch, over, slots, max_seq, batch = POOL_CELLS[cell]
+    cfg = dataclasses.replace(get_config(arch), **over)
+    mod = family_module(cfg)
+    params = _structs(jax.eval_shape(functools.partial(mod.init, cfg, tp=1),
+                                     jax.random.PRNGKey(0)), one_chip)
+    cache = _structs(jax.eval_shape(functools.partial(
+        mod.init_paged_cache, cfg, slots, slots * max_seq, max_seq, 1)),
+        one_chip)
+    decode, _, _ = _paged_jitted_steps(cfg, 1, "xla")
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    compiled = decode.lower(params, cache, i32((batch, 1)), i32((batch,)),
+                            i32((batch, max_seq))).compile()
+
+    leaves = jax.tree_util.tree_leaves(cache)
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    stacked = {x.size for x in leaves}
+    layer = {x.size // cfg.n_layers for x in leaves}
+    ops = [(m.group(0), m.group(2),
+            math.prod(map(int, filter(None, m.group(1).split(",")))))
+           for m in _BF16_OP.finditer(compiled.as_text())]
+    written = [t for t, op, n in ops
+               if op in _WRITES and n in stacked | layer]
+    assert not written, written
+    read = [t for t, op, n in ops if op == "dynamic-slice"
+            and n in stacked | layer]
+    staged = [x for x in leaves if x.size // cfg.n_layers * x.dtype.itemsize
+              <= L.STAGE_POOL_BYTES]
+    assert len(staged) in (0, len(leaves)), staged
+    assert len(read) == len(staged) and not any(
+        n in stacked for _, op, n in ops if op == "dynamic-slice"), read
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= pool_bytes
+    assert ma.temp_size_in_bytes < (2 if staged else 1) * pool_bytes \
+        / cfg.n_layers
