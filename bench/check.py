@@ -13,8 +13,6 @@ from __future__ import annotations
 
 import numpy as np
 
-from bench import reference
-
 
 def sample(run, seed: int, n: int) -> list[int]:
     """Plan indices of ``n`` finished requests: the one with the most served
@@ -72,29 +70,29 @@ def summary(g: np.ndarray) -> dict:
             "tokens_compared": int(g.size)}
 
 
-def served_gap(weights, dims, run, idxs, *, pad_to: int) -> dict:
-    """``summary`` of the served tokens' gaps (empty when nothing
-    finished)."""
+def served_gap(family, weights, dims, run, idxs, *, pad_to: int) -> dict:
+    """``summary`` of the served tokens' gaps against the reference of
+    ``family`` (empty when nothing finished)."""
     if not idxs:
         return {"tokens_compared": 0}
     seqs, at, served = sequences(run, idxs)
-    return summary(gaps(_by_request(weights, dims, seqs, at, pad_to),
+    return summary(gaps(_by_request(family, weights, dims, seqs, at, pad_to),
                         served))
 
 
-def _by_request(weights, dims, seqs, at, pad_to, mode=None):
+def _by_request(family, weights, dims, seqs, at, pad_to, mode=None):
     """The reference's logits, one request at a time, so that a batch of
     long contexts need not fit at once."""
-    return [reference.logits(weights, dims, [s], [a], pad_to=pad_to,
-                             mode=mode)[0] for s, a in zip(seqs, at)]
+    return [family.logits(weights, dims, [s], [a], pad_to=pad_to,
+                          mode=mode)[0] for s, a in zip(seqs, at)]
 
 
-def control_gap(weights, dims, run, idxs, *, pad_to: int,
+def control_gap(family, weights, dims, run, idxs, *, pad_to: int,
                 mode: str) -> tuple[dict, dict]:
     """(``summary`` of the served tokens' gaps, of the gaps of the tokens
     the reference computed in ``mode`` puts first), at the same positions."""
     seqs, at, served = sequences(run, idxs)
-    ref = _by_request(weights, dims, seqs, at, pad_to)
-    low = _by_request(weights, dims, seqs, at, pad_to, mode)
+    ref = _by_request(family, weights, dims, seqs, at, pad_to)
+    low = _by_request(family, weights, dims, seqs, at, pad_to, mode)
     return (summary(gaps(ref, served)),
             summary(gaps(ref, [x.argmax(-1) for x in low])))

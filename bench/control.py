@@ -5,8 +5,9 @@ control's on the same prompts and tokens.  Run on the chip, in one process.
 
 For each seed: one run of the cell as ``run.py`` makes it (a short window at
 the cell's own load, drained, its sample checked against the reference),
-then the reference computed in float8 (``reference.py``) in the program's
-place, read at every position of the same prompts and served tokens.
+then the family's reference computed in float8 (``reference.mm``) in the
+program's place, on the first chip, read at every position of the same
+prompts and served tokens.
 Prints one JSON line per seed: the widest and the mean gap of the served
 tokens (the lower readings' material) and of the control's first choices
 (the upper readings').  The benchmark's own runs never run this.
@@ -26,8 +27,8 @@ sys.path[:0] = [str(Path(__file__).resolve().parents[1]),
 def main(argv=None) -> int:
     import jax
 
-    from bench import check, reference, spec, weights
-    from bench.run import NoChip, run_cell
+    from bench import check, spec, weights
+    from bench.run import NoChip, cell_mesh, run_cell
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -36,8 +37,8 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     cell = spec.load_cell(args.workload)
-    cfg = spec.model_config(cell.config)
-    dims = reference.Dims.of(cell.config)
+    cfg = spec.model_config(cell.config, cell.model_axis)
+    dims = cell.family.Dims.of(cell.config)
     for seed in args.seeds:
         kept = {}
         try:
@@ -47,14 +48,16 @@ def main(argv=None) -> int:
             print(f"no chip: {e}", file=sys.stderr)
             return 3
         run = kept["run"]
-        w = jax.block_until_ready(weights.make(cfg, seed))
+        w = jax.block_until_ready(weights.make(
+            cell.family, cfg, seed,
+            cell_mesh(cell, jax.devices()[:cell.chips])))
         idxs = check.sample(run, seed, cell.correct["sample_requests"])
         line = {"seed": seed, "correct": result["correct"],
                 "served": {k: c["value"]
                            for k, c in result["checks"].items()},
                 "attempted": result["attempted"], "failed": result["failed"]}
         line["fp8"] = check.control_gap(
-            w, dims, run, idxs, pad_to=cell.engine["max_seq"],
+            cell.family, w, dims, run, idxs, pad_to=cell.engine["max_seq"],
             mode="fp8")[1]
         print(json.dumps(line), flush=True)
         del w, run, kept

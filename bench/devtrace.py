@@ -1,10 +1,17 @@
 """Capture a profiler trace of part of the window and reduce it.
 
-The reduction is the yardstick's: busy time is the union of the intervals
-in which a program or an XLA op ran on a device; each call of a jitted step that the
-harness annotated on the host is matched to the device execution of its
-program, in order; idle gaps are named by the harness span that covered
-them on the host.
+The reduction is the yardstick's, and keeps each device apart:
+
+- a device's busy time is the union of the intervals in which a program or
+  an XLA op ran on it; busy and idle time are averaged over the devices;
+- each call of a jitted step that the harness annotated on the host is
+  matched, on each device, to that device's execution of its program, in
+  order; the call's execution runs from its first start on any device to
+  its last end;
+- idle gaps are the stretches in which no device ran anything, named by the
+  harness span that covered them on the host.
+
+On a trace of one device each of these is that device's own reading.
 """
 from __future__ import annotations
 
@@ -23,6 +30,8 @@ class Interval:
     name: str
     start: float    # seconds, on the trace's clock
     end: float
+    # the device's plane; "" for a host span
+    device: str = dataclasses.field(default="", kw_only=True)
 
     @property
     def dur(self) -> float:
@@ -30,14 +39,26 @@ class Interval:
 
 
 @dataclasses.dataclass
+class Execution(Interval):
+    """One call's executions of its program, one part per device, spanning
+    the first start to the last end."""
+
+    parts: list[Interval] = dataclasses.field(default_factory=list)
+
+
+@dataclasses.dataclass
 class Reduced:
     """What the readers of per-layer metrics get from one trace."""
 
     window: Interval
-    ops: list[Interval]          # device ops, all devices
-    modules: list[Interval]      # device program executions
+    ops: list[Interval]          # device ops, every device, by start
+    modules: list[Interval]      # device program executions, by start
     host: list[Interval]         # the harness's own host spans
-    n_devices: int
+    devices: list[str]           # the planes that ran a program
+
+    @property
+    def n_devices(self) -> int:
+        return len(self.devices)
 
 
 def start(directory: str) -> None:
@@ -75,25 +96,23 @@ def reduce_planes(planes) -> Reduced:
     """Planes as ``ProfileData`` gives them (or any objects with ``name``,
     ``lines``; lines with ``name``, ``events``; events with ``name``,
     ``start_ns``, ``duration_ns``)."""
-    ops, modules, host = [], [], []
-    n_devices = 0
+    ops, modules, host, devices = [], [], [], []
 
-    def iv(ev):
+    def iv(ev, device=""):
         return Interval(ev.name, ev.start_ns * 1e-9,
-                        (ev.start_ns + ev.duration_ns) * 1e-9)
+                        (ev.start_ns + ev.duration_ns) * 1e-9, device=device)
 
     for plane in planes:
         if plane.name.startswith("/device:") and "CPU" not in plane.name:
             # a chip's trace may hold planes beside its core's (the TPU's
             # non-core activity): a device is a plane that ran programs
-            ran = False
-            for line in plane.lines:
-                if line.name == "XLA Ops":
-                    ops.extend(iv(e) for e in line.events)
-                elif line.name == "XLA Modules":
-                    modules.extend(iv(e) for e in line.events)
-                    ran = ran or bool(line.events)
-            n_devices += ran
+            lines = {line.name: line.events for line in plane.lines}
+            if lines.get("XLA Modules"):
+                devices.append(plane.name)
+                ops.extend(iv(e, plane.name)
+                           for e in lines.get("XLA Ops", ()))
+                modules.extend(iv(e, plane.name)
+                               for e in lines["XLA Modules"])
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
                 host.extend(iv(e) for e in line.events
@@ -109,13 +128,18 @@ def reduce_planes(planes) -> Reduced:
     host = sorted((h for h in host if h.name != WINDOW_SPAN),
                   key=lambda h: h.start)
     return Reduced(w, sorted(ops, key=lambda o: o.start),
-                   sorted(modules, key=lambda m: m.start), host,
-                   max(1, n_devices))
+                   sorted(modules, key=lambda m: m.start), host, devices)
 
 
 def clip(ivs: list[Interval], w: Interval) -> list[Interval]:
-    return [Interval(i.name, max(i.start, w.start), min(i.end, w.end))
+    return [Interval(i.name, max(i.start, w.start), min(i.end, w.end),
+                     device=i.device)
             for i in ivs if i.end > w.start and i.start < w.end]
+
+
+def on(ivs: list[Interval], device: str | None) -> list[Interval]:
+    """The intervals of ``device``; all of them for None."""
+    return ivs if device is None else [i for i in ivs if i.device == device]
 
 
 def union(ivs: list[Interval]) -> list[tuple[float, float]]:
@@ -129,22 +153,28 @@ def union(ivs: list[Interval]) -> list[tuple[float, float]]:
     return [(a, b) for a, b in out]
 
 
-def busy(r: Reduced, w: Interval | None = None) -> list[tuple[float, float]]:
-    """Disjoint intervals inside the window (or ``w``) in which the device
-    ran a program or an op.  A program's execution counts whole: inside it
-    the chip also waits on its own copies, which the op line does not
-    show."""
-    return union(clip(r.modules + r.ops, w or r.window))
+def busy(r: Reduced, w: Interval | None = None,
+         device: str | None = None) -> list[tuple[float, float]]:
+    """Disjoint intervals inside the window (or ``w``) in which ``device``
+    (any device, for None) ran a program or an op.  A program's execution
+    counts whole: inside it the chip also waits on its own copies, which
+    the op line does not show."""
+    return union(clip(on(r.modules, device) + on(r.ops, device),
+                      w or r.window))
+
+
+def seconds(spans: list[tuple[float, float]]) -> float:
+    return sum(b - a for a, b in spans)
 
 
 def busy_seconds(r: Reduced, w: Interval | None = None) -> float:
-    """Busy seconds inside the window (or ``w``), averaged over the devices
-    (their intervals are pooled, so on one chip it is that chip's busy
-    time)."""
-    return sum(b - a for a, b in busy(r, w)) / r.n_devices
+    """Busy seconds inside the window (or ``w``), averaged over the
+    devices."""
+    return sum(seconds(busy(r, w, d)) for d in r.devices) / r.n_devices
 
 
 def idle_gaps(r: Reduced) -> list[tuple[float, float]]:
+    """The stretches of the window in which no device ran anything."""
     spans = busy(r)
     edges = [r.window.start] + [x for s in spans for x in s] + [r.window.end]
     return [(a, b) for a, b in zip(edges[::2], edges[1::2]) if b > a]
@@ -162,24 +192,37 @@ def gap_owner(r: Reduced, gap: tuple[float, float]) -> str:
     return name
 
 
-def calls(r: Reduced, program: str) -> list[tuple[Interval, Interval]]:
+def calls(r: Reduced, program: str) -> list[tuple[Interval, Execution]]:
     """(host span, device execution) of every annotated call of the jitted
-    step inside the window.  The k-th execution of the step's program on the
-    device belongs to the k-th annotated call on the host: one device runs
-    its programs in the order they were enqueued, and an execution cannot
-    start before its call did.  ``program`` is a substring of the step's
-    module name."""
-    steps = [m for m in r.modules if program in m.name]
-    pairs, j = [], 0
-    for h in (h for h in r.host if h.name in CALL_SPANS):
-        while j < len(steps) and steps[j].start < h.start:
+    step inside the window.  On each device the k-th execution of the
+    step's program belongs to the k-th annotated call on the host: a device
+    runs its programs in the order they were enqueued, and an execution
+    cannot start before its call did.  A call counts where every device
+    that ran the program holds its execution.  ``program`` is a substring
+    of the step's module name."""
+    spans = [h for h in r.host if h.name in CALL_SPANS]
+    parts: list[list[Interval]] = [[] for _ in spans]
+    ran = 0
+    for d in r.devices:
+        steps = [m for m in on(r.modules, d) if program in m.name]
+        ran += bool(steps)
+        j = 0
+        for k, h in enumerate(spans):
+            while j < len(steps) and steps[j].start < h.start:
+                j += 1
+            if j == len(steps):
+                break
+            parts[k].append(steps[j])
             j += 1
-        if j == len(steps):
-            break
-        pairs.append((h, steps[j]))
-        j += 1
-    return [(h, m) for h, m in pairs
-            if r.window.start <= h.start and m.end <= r.window.end]
+    out = []
+    for h, ps in zip(spans, parts):
+        if not ps or len(ps) < ran:
+            continue
+        x = Execution(ps[0].name, min(p.start for p in ps),
+                      max(p.end for p in ps), parts=ps)
+        if r.window.start <= h.start and x.end <= r.window.end:
+            out.append((h, x))
+    return out
 
 
 def self_times(ops: list[Interval]) -> dict[str, float]:
@@ -199,7 +242,11 @@ def self_times(ops: list[Interval]) -> dict[str, float]:
 
 
 def top_ops(r: Reduced, n: int = 10) -> list[list]:
-    total = self_times(clip(r.ops, r.window))
+    """The ops that ran longest, by self time summed over the devices."""
+    total: dict[str, float] = {}
+    for d in r.devices:
+        for k, v in self_times(clip(on(r.ops, d), r.window)).items():
+            total[k] = total.get(k, 0.0) + v
     return [[k[:160], v] for k, v in
             sorted(total.items(), key=lambda kv: -kv[1])[:n]]
 

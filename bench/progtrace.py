@@ -17,7 +17,9 @@ same ``.xplane.pb`` for what the program put there itself:
 
 prints, for a traced run, the idle time inside the engine's ticks by the
 span that held the host, and the batched decode program's device time by
-scope.
+scope.  Each device is read apart and the readings averaged over the
+devices, as ``devtrace`` does: idle inside a tick, idle by span, and a
+scope's time per call.
 """
 from __future__ import annotations
 
@@ -54,7 +56,7 @@ class Op(devtrace.Interval):
 @dataclasses.dataclass
 class Profile:
     spans: list[Span]            # the program's host spans, by start
-    ops: list[Op]                # device ops, all devices, by start
+    ops: list[Op]                # device ops, every device, by start
 
 
 def innermost(op_name: str) -> str | None:
@@ -203,7 +205,7 @@ def _host_spans(path: str) -> list[Span]:
     return spans
 
 
-def scoped(events: list[tuple]) -> list[Op]:
+def scoped(events: list[tuple], device: str) -> list[Op]:
     """The ops of one device's ``XLA Ops`` line, each with its scope: that
     of its own ``op_name``, else that of the op enclosing it on the line.
     On the v5e the layer scan's ``while`` op and the copies XLA adds carry
@@ -224,12 +226,12 @@ def scoped(events: list[tuple]) -> list[Op]:
     for k, up in enumerate(parent):     # a parent comes before its children
         if scope[k] is None and up >= 0:
             scope[k] = scope[up]
-    return [Op(name, t * 1e-9, (t + d) * 1e-9, scope[k])
+    return [Op(name, t * 1e-9, (t + d) * 1e-9, scope[k], device=device)
             for k, (t, d, name, _) in enumerate(events)]
 
 
 def reduce(spans: list[Span], planes: list[dict]) -> Profile:
-    ops = [o for p in planes for o in scoped(p["ops"])]
+    ops = [o for p in planes for o in scoped(p["ops"], p["name"])]
     return Profile(sorted(spans, key=lambda s: s.start),
                    sorted(ops, key=lambda o: o.start))
 
@@ -283,18 +285,29 @@ def ticks(p: Profile, window: devtrace.Interval) -> list[Span]:
     return out
 
 
+def _busy(trace: devtrace.Reduced) -> list[list[tuple[float, float]]]:
+    return [devtrace.busy(trace, device=d) for d in trace.devices]
+
+
+def _idle(busy: list[list[tuple[float, float]]], a: float, b: float
+          ) -> float:
+    """Seconds of [a, b] in which a device sat idle, averaged over the
+    devices."""
+    return sum((b - a) - _covered(x, a, b) for x in busy) / len(busy)
+
+
 def tick_idle(p: Profile, trace: devtrace.Reduced) -> list[float]:
-    """Seconds the device sat idle inside each of ``ticks``."""
-    busy = devtrace.busy(trace)
-    return [t.dur - _covered(busy, t.start, t.end)
-            for t in ticks(p, trace.window)]
+    """Seconds the devices sat idle inside each of ``ticks``, averaged over
+    the devices."""
+    busy = _busy(trace)
+    return [_idle(busy, t.start, t.end) for t in ticks(p, trace.window)]
 
 
 def idle_by_span(p: Profile, trace: devtrace.Reduced) -> dict[str, float]:
-    """The device's idle seconds inside ``ticks``, each piece put down to
-    the innermost span that held the host then (``serve.step`` for its
-    own self time)."""
-    busy = devtrace.busy(trace)
+    """The devices' idle seconds inside ``ticks``, averaged over the
+    devices, each piece put down to the innermost span that held the host
+    then (``serve.step`` for its own self time)."""
+    busy = _busy(trace)
     starts = [s.start for s in p.spans]
     out: dict[str, float] = {}
     for t in ticks(p, trace.window):
@@ -303,7 +316,7 @@ def idle_by_span(p: Profile, trace: devtrace.Reduced) -> dict[str, float]:
         inner = [s for s in p.spans[i:j] if s.end <= t.end]
         cuts = sorted({x for s in inner for x in (s.start, s.end)})
         for a, b in zip(cuts, cuts[1:]):
-            idle = (b - a) - _covered(busy, a, b)
+            idle = _idle(busy, a, b)
             if idle <= 0:
                 continue
             owner = _innermost(inner, 0.5 * (a + b)).name
@@ -323,29 +336,35 @@ def scope_seconds(p: Profile, executions: list[devtrace.Interval]
                   ) -> dict[str | None, float]:
     """Device seconds, by innermost scope (None: outside every scope), of
     the ops that ran inside ``executions`` of one program (whose op names
-    are unique), each op less the ops nested in it."""
-    starts = [o.start for o in p.ops]
-    ops = []
-    for x in executions:
-        i = bisect.bisect_left(starts, x.start)
-        j = bisect.bisect_right(starts, x.end)
-        ops.extend(o for o in p.ops[i:j] if o.end <= x.end)
-    scope = {o.name: o.scope for o in ops}
+    are unique), each op less the ops nested in it.  Each execution is
+    one device's; the seconds are averaged over the devices."""
+    devices = sorted({x.device for x in executions})
     out: dict[str | None, float] = {}
-    for name, t in devtrace.self_times(ops).items():
-        out[scope[name]] = out.get(scope[name], 0.0) + t
-    return out
+    for d in devices:
+        mine = devtrace.on(p.ops, d)
+        starts = [o.start for o in mine]
+        ops = []
+        for x in executions:
+            if x.device != d:
+                continue
+            i = bisect.bisect_left(starts, x.start)
+            j = bisect.bisect_right(starts, x.end)
+            ops.extend(o for o in mine[i:j] if o.end <= x.end)
+        scope = {o.name: o.scope for o in ops}
+        for name, t in devtrace.self_times(ops).items():
+            out[scope[name]] = out.get(scope[name], 0.0) + t
+    return {k: v / len(devices) for k, v in out.items()}
 
 
 def decode_scope_ms(r, scope: str) -> float | None:
     """Device ms per traced decode call of the ops whose innermost scope
-    is ``scope``; None where no op of those calls carries it (a program
-    without the scope)."""
+    is ``scope``, averaged over the devices; None where no op of those
+    calls carries it (a program without the scope)."""
     p = of(r)
     calls = r.device_calls("decode")
     if p is None or not calls:
         return None
-    by = scope_seconds(p, [d for _, d in calls])
+    by = scope_seconds(p, [x for _, d in calls for x in d.parts])
     return 1e3 * by[scope] / len(calls) if scope in by else None
 
 
@@ -355,11 +374,13 @@ def summary(directory) -> dict:
     trace = devtrace.load(str(directory))
     p = load(directory)
     idle = tick_idle(p, trace)
-    calls = [m for m in devtrace.clip(trace.modules, trace.window)
+    # each device's executions of the decode program inside the window
+    execs = [m for m in devtrace.clip(trace.modules, trace.window)
              if DECODE_PROGRAM in m.name and m.start > trace.window.start
              and m.end < trace.window.end]
-    by = scope_seconds(p, calls)
+    by = scope_seconds(p, execs)
     op_s = sum(by.values())
+    calls = len(execs) // trace.n_devices
     return {
         "ticks": len(idle),
         "tick_ms_median": 1e3 * statistics.median(
@@ -368,12 +389,12 @@ def summary(directory) -> dict:
         else None,
         "idle_ms_by_span": {k: 1e3 * v for k, v in sorted(
             idle_by_span(p, trace).items(), key=lambda kv: -kv[1])},
-        "decode_calls": len(calls),
-        "decode_call_ms": 1e3 * sum(m.dur for m in calls) / len(calls)
-        if calls else None,
-        "decode_ms_by_scope": {str(k): 1e3 * v / len(calls) for k, v in
+        "decode_calls": calls,
+        "decode_call_ms": 1e3 * sum(m.dur for m in execs) / len(execs)
+        if execs else None,
+        "decode_ms_by_scope": {str(k): 1e3 * v / calls for k, v in
                                sorted(by.items(), key=lambda kv: -kv[1])}
-        if calls else {},
+        if execs else {},
         "decode_scoped_share": 1.0 - by.get(None, 0.0) / op_s if op_s
         else None,
         # the window's longest idle gaps and the span that held the host

@@ -24,10 +24,11 @@ from bench.stats import Window
 class Reading:
     run: object                 # serving.Run after the drain
     cfg: object                 # the program's ModelConfig
-    peaks: dict | None          # peaks.json entry of the device
+    peaks: dict | None          # peaks.json entry of one chip
     setup_s: float
     trace: devtrace.Reduced | None = None
     traced: Window | None = None    # the traced sub-window, host clock
+    family: object = None       # the cell's family: its counts
 
     def served(self):
         return list(self.run.served.values())

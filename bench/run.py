@@ -2,8 +2,10 @@
 
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
-Set-up builds the weights on the device from the seed, loads or compiles
-every program the cell's traffic uses and warms each shape; the window then
+Set-up builds the weights on the device from the seed (on a cell of
+several chips, sharded over the cell's mesh as the program shards them),
+loads or compiles every program the cell's traffic uses and warms each
+shape; the window then
 offers the cell's load to ``PagedServeEngine`` for ``--seconds``; the run
 drains what it owes, reads its metrics, frees the engine and checks a sample
 of the served tokens against the plain reference (``check.py``).  With
@@ -115,6 +117,18 @@ def enable_compile_cache() -> str:
     return where
 
 
+def cell_mesh(cell, devices):
+    """The cell's ``(data, model)`` mesh over ``devices``; None on one
+    chip."""
+    if cell.mesh is None:
+        return None
+    from bench.spec import MESH_AXES
+    from repro.launch.mesh import make_mesh
+
+    return make_mesh(tuple(cell.mesh[a] for a in MESH_AXES), MESH_AXES,
+                     devices=devices)
+
+
 def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
              require_chip: bool = True, root: Path = REPO,
              break_path=None) -> dict:
@@ -123,8 +137,7 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     which drive a run on the CPU with the timed path broken."""
     import jax
 
-    from bench import check, devtrace, readings, reference, serving, spec, \
-        weights
+    from bench import check, devtrace, readings, serving, spec, weights
 
     cell = spec.load_cell(name, root)
     devices = jax.devices()
@@ -137,10 +150,11 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     if require_chip:
         enable_compile_cache()
 
-    cfg = spec.model_config(cell.config)
-    w = jax.block_until_ready(weights.make(cfg, seed))
+    cfg = spec.model_config(cell.config, cell.model_axis, root)
+    mesh = cell_mesh(cell, devices)
+    w = jax.block_until_ready(weights.make(cell.family, cfg, seed, mesh))
     run = serving.Run(cell, cfg, w, seed=seed, seconds=seconds,
-                      annotate=trace)
+                      annotate=trace, mesh=mesh)
     if break_path is not None:
         break_path(run)
     run.warm_up()
@@ -157,7 +171,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     mem_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                    for d in devices)
 
-    reading = readings.Reading(run, cfg, peaks, setup_s)
+    reading = readings.Reading(run, cfg, peaks, setup_s,
+                               family=cell.family)
     cost = None
     if tracer is not None and tracer.h0 is not None:
         reading.trace = devtrace.load(tracer.directory)
@@ -202,8 +217,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     run.free()
     gc.collect()
     t_ref = time.perf_counter()
-    got = check.served_gap(w, reference.Dims.of(cell.config), run, idxs,
-                           pad_to=cell.engine["max_seq"])
+    got = check.served_gap(cell.family, w, cell.family.Dims.of(cell.config),
+                           run, idxs, pad_to=cell.engine["max_seq"])
     compared = got["tokens_compared"]
     slots = sorted({run.served[i].slot for i in idxs}, key=str)
     print(f"reference: {len(idxs)} requests from slots {slots}, {compared} "

@@ -1,6 +1,6 @@
 """Drive ``PagedServeEngine`` through set-up, the window and the drain.
 
-One object per run.  Set-up builds the weights from the seed, warms every
+One object per run, on one chip or over the cell's mesh.  Set-up warms every
 shape the run's traffic will use, and (closed loop) prefills each client's
 first request.  The window then offers the planned load on the harness's
 clock and records every output token when ``step()`` returns it.  After the
@@ -51,7 +51,7 @@ class Call:
 
 class Run:
     def __init__(self, cell, cfg, weights, *, seed: int, seconds: float,
-                 annotate: bool):
+                 annotate: bool, mesh=None):
         from repro.launch.serve import PagedServeEngine
 
         self.cell, self.cfg, self.seconds = cell, cfg, seconds
@@ -63,7 +63,7 @@ class Run:
         self.engine = PagedServeEngine(
             cfg, weights, slots=e["slots"], max_seq=e["max_seq"],
             page_size=e["page_size"], prefill_chunk=e["prefill_chunk"],
-            clock=clock)
+            mesh=mesh, clock=clock)
         self.calls: list[Call] = []
         self.ticks: list[tuple[float, float]] = []   # engine.step, host clock
         self.served: dict[int, Served] = {}     # plan idx -> record

@@ -43,7 +43,7 @@ def main(argv=None) -> int:
     import jax
 
     from bench import serving, spec, stats, weights
-    from bench.run import enable_compile_cache
+    from bench.run import cell_mesh, enable_compile_cache
 
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--workload", required=True)
@@ -57,12 +57,14 @@ def main(argv=None) -> int:
         return 3
     enable_compile_cache()
     cell = spec.load_cell(args.workload)
-    cfg = spec.model_config(cell.config)
-    w = jax.block_until_ready(weights.make(cfg, args.seed))
+    cfg = spec.model_config(cell.config, cell.model_axis)
+    mesh = cell_mesh(cell, jax.devices()[:cell.chips])
+    w = jax.block_until_ready(weights.make(cell.family, cfg, args.seed,
+                                           mesh))
     for rate in args.rates:
         c = dataclasses.replace(cell, load={"rate_per_s": rate})
         run = serving.Run(c, cfg, w, seed=args.seed, seconds=args.seconds,
-                          annotate=False)
+                          annotate=False, mesh=mesh)
         run.warm_up()
         run.window_open()
         run.drain()

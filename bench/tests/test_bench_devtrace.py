@@ -1,10 +1,16 @@
 """The trace reduction on a small synthetic trace: busy union, idle share,
-calls matched to their device executions, idle gaps named by host span."""
+calls matched to their device executions, idle gaps named by host span;
+each device read apart on four devices; and every reading of the one-device
+trace against goldens recorded from the tree before devices were kept
+apart."""
+import copy
+import json
 from types import SimpleNamespace as NS
 
 import pytest
 
-from bench import devtrace
+from bench import devtrace, spec
+from bench.spec import BENCH
 
 MS = 1_000_000   # ns
 
@@ -130,24 +136,10 @@ def test_readers_pair_device_executions_with_the_harness_records():
 
 
 def test_step_mfu_is_model_flops_over_the_device_busy_time():
-    import json
+    from bench.readings import reader
 
-    from bench import counters, spec
-    from bench.readings import Reading, reader
-    from bench.serving import Call
-    from bench.spec import BENCH
-    from bench.stats import Window
-
-    with open(BENCH / "configs" / "qwen3-8b-l12.json") as f:
-        cfg = spec.model_config(json.load(f))
-    host0 = 500.0
-    calls = [Call("decode", host0 + 0.002, contexts=[10, 20]),
-             Call("prefill", host0 + 0.004, chunk=(0, 16, False)),
-             Call("decode", host0 + 0.041, contexts=[11, 21, 5])]
-    peaks = {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
-    reading = Reading(run=NS(calls=calls), cfg=cfg, peaks=peaks, setup_s=0,
-                      trace=devtrace.reduce_planes(planes()),
-                      traced=Window(host0, host0 + 0.1))
+    reading = qwen3_reading(planes())
+    cfg, counters = reading.cfg, reading.family
     flops = counters.decode_step(cfg, [10, 20])[0] \
         + counters.prefill_chunk(cfg, 0, 16, False) \
         + counters.decode_step(cfg, [11, 21, 5])[0]
@@ -215,3 +207,128 @@ def test_the_profiler_runs_without_the_python_tracer(tmp_path):
              for e in ln.events]
     assert devtrace.WINDOW_SPAN in names
     assert len(names) < 1000
+
+
+# -- several devices ----------------------------------------------------------
+
+PEAKS = {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
+
+
+def qwen3_reading(p):
+    """A reading of the trace ``p`` as the qwen3-8b-l12 cell's, with the
+    calls the harness recorded for ``planes()``."""
+    from bench.readings import Reading
+    from bench.serving import Call
+    from bench.stats import Window
+
+    with open(BENCH / "configs" / "qwen3-8b-l12.json") as f:
+        config = json.load(f)
+    host0 = 500.0      # the harness's clock when the traced span opened
+    calls = [Call("decode", host0 + 0.002, contexts=[10, 20]),
+             Call("prefill", host0 + 0.004, chunk=(0, 16, False)),
+             Call("decode", host0 + 0.041, contexts=[11, 21, 5])]
+    counted = {"generated": (100, 130), "decode_steps": (40, 52)}
+    return Reading(run=NS(calls=calls, counters=counted),
+                   cfg=spec.model_config(config), peaks=PEAKS, setup_s=0,
+                   trace=devtrace.reduce_planes(p),
+                   traced=Window(host0, host0 + 0.1),
+                   family=spec.family(config))
+
+
+def four(edit=None):
+    """``planes()`` with its device on four devices; ``edit(i, plane)``
+    changes device ``i``'s copy."""
+    host, dev, cpu = planes()
+    devs = []
+    for i in range(4):
+        d = copy.deepcopy(dev)
+        d.name = f"/device:TPU:{i}"
+        if edit is not None:
+            edit(i, d)
+        devs.append(d)
+    return [host] + devs + [cpu]
+
+
+READERS = ("decode_step_ms.long", "prefill_chunk_ms.chat",
+           "decode_batch_mean", "device_idle_pct.chat", "step_mfu.chat",
+           "decode_step_roofline.chat")
+
+
+def readings(p) -> dict:
+    """Every reading of ``p`` that the harness takes from it."""
+    from bench.readings import reader
+
+    r = qwen3_reading(p)
+    t = r.trace
+    out = {"busy_s": devtrace.busy_seconds(t),
+           "idle_gaps": devtrace.idle_gaps(t),
+           "calls": [[h.name, h.start, h.end, d.start, d.end]
+                     for h, d in devtrace.calls(t, "decode_step")],
+           "top_ops": devtrace.top_ops(t), "top_gaps": devtrace.top_gaps(t)}
+    out.update((m, reader(m)(r)) for m in READERS)
+    return out
+
+
+def test_readings_match_the_parent_tree():
+    golden = json.loads((BENCH / "tests" / "data" / "parity.json")
+                        .read_text())["trace"]["devtrace"]
+    got = json.loads(json.dumps(readings(planes())))    # tuples as lists
+    assert got == golden
+
+
+def test_four_identical_devices_read_as_one():
+    one, many = readings(planes()), readings(four())
+    assert devtrace.reduce_planes(four()).n_devices == 4
+    for k in ("idle_gaps", "calls", "top_gaps"):
+        assert many[k] == one[k], k
+    for k in ("busy_s", "decode_step_ms.long", "prefill_chunk_ms.chat",
+              "decode_batch_mean", "device_idle_pct.chat"):
+        assert many[k] == pytest.approx(one[k]), k
+    # the same model work over four chips' busy time and peaks
+    for k in ("step_mfu.chat", "decode_step_roofline.chat"):
+        assert many[k] == pytest.approx(one[k] / 4), k
+    assert dict(many["top_ops"]) == pytest.approx(
+        {k: 4 * v for k, v in one["top_ops"]})
+
+
+def test_a_device_that_starts_late_stretches_the_call():
+    def late(i, plane):        # the first decode call, 2 ms late on TPU:3
+        if i == 3:
+            for ln in plane.lines:
+                for e in ln.events:
+                    if 3 * MS <= e.start_ns < 13 * MS:
+                        e.start_ns += 2 * MS
+
+    got = readings(four(late))
+    assert [(round(c[3] * 1e3, 6), round(c[4] * 1e3, 6))
+            for c in got["calls"]] == [(3, 15), (15, 30), (45, 67)]
+    assert got["decode_step_ms.long"] == pytest.approx((12 + 22) / 2)
+
+
+def test_an_idle_device_shows_in_the_averaged_idle():
+    def idle(i, plane):        # TPU:3 runs no commit and no copy at 13 ms
+        if i == 3:
+            for ln in plane.lines:
+                ln.events = [e for e in ln.events if e.start_ns != 13 * MS]
+
+    one, got = readings(planes()), readings(four(idle))
+    # busy 49 ms on three devices, 47 on the fourth
+    assert got["busy_s"] == pytest.approx((3 * 0.049 + 0.047) / 4)
+    assert got["device_idle_pct.chat"] == pytest.approx(
+        100 * (1 - (3 * 0.049 + 0.047) / 4 / 0.1))
+    assert got["device_idle_pct.chat"] > one["device_idle_pct.chat"]
+    # no new gap: the others ran while TPU:3 sat idle
+    assert got["idle_gaps"] == one["idle_gaps"]
+
+
+def test_readers_reach_the_counts_through_the_family():
+    from bench.readings import reader
+
+    r = qwen3_reading(planes())
+    r.family = NS(decode_step=lambda cfg, contexts: (1e12, 0.0),
+                  prefill_chunk=lambda cfg, *chunk: 1e12)
+    # three calls of 1 TFLOP over 49 ms busy; two decode calls, 32 ms
+    assert reader("step_mfu.chat")(r) == pytest.approx(
+        100 * 3e12 / (0.049 * 197e12))
+    assert reader("decode_step_roofline.chat")(r) == pytest.approx(
+        100 * 2e12 / 197e12 / 0.032)

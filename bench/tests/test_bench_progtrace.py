@@ -2,12 +2,17 @@
 innermost-scope attribution (an op outside every scope, ops XLA left
 without a name inside the layer scan), the idle time inside the engine's
 ticks and the span that held the host, and the readers of the per-layer
-metrics; the wire decoder on a serialized XSpace."""
+metrics; each device read apart on four devices; every reading of the
+one-device trace against goldens recorded from the tree before devices
+were kept apart; the wire decoder on a serialized XSpace."""
+import copy
+import json
 from types import SimpleNamespace as NS
 
 import pytest
 
 from bench import devtrace, progtrace
+from bench.spec import BENCH
 
 MS = 1_000_000   # ns
 BODY = "jit(decode_step_batch)/layers/while/body/closed_call"
@@ -22,23 +27,26 @@ def line(name, *events):
     return NS(name=name, events=list(events))
 
 
-def harness_trace() -> devtrace.Reduced:
-    """What devtrace keeps: the window, the harness's call spans, the
-    device's program executions and ops."""
+def harness_planes(n: int = 1) -> list:
+    """The planes devtrace reads: the window, the harness's call spans,
+    ``n`` devices' program executions and ops."""
     host = NS(name="/host:CPU", lines=[line(
         "python",
         ev(devtrace.WINDOW_SPAN, 0, 100),
         ev("bench.decode_step", 17, 1),
         ev("bench.prefill_chunk", 41, 1),
         ev("bench.decode_step", 62, 0.3))])
-    dev = NS(name="/device:TPU:0", lines=[
+    return [host] + [NS(name=f"/device:TPU:{i}", lines=[
         line("XLA Modules",
              ev("jit_decode_step_batch(1)", 18, 20),
              ev("jit_decode_step_prefill(2)", 42, 8),
              ev("jit_decode_step_batch(1)", 62.5, 25.5)),
         line("XLA Ops", ev("fusion", 18, 20), ev("fusion", 42, 8),
-             ev("fusion", 62.5, 25.5))])
-    return devtrace.reduce_planes([host, dev])
+             ev("fusion", 62.5, 25.5))]) for i in range(n)]
+
+
+def harness_trace() -> devtrace.Reduced:
+    return devtrace.reduce_planes(harness_planes())
 
 
 def span(name, a, b, **args):
@@ -118,7 +126,8 @@ def test_ops_without_a_name_take_the_scope_that_encloses_them():
 
 def test_scope_seconds_counts_self_time():
     p = profile()
-    first = devtrace.Interval("jit_decode_step_batch(1)", 0.018, 0.038)
+    first = devtrace.Interval("jit_decode_step_batch(1)", 0.018, 0.038,
+                              device="/device:TPU:0")
     by = {k: round(v * 1e3, 6) for k, v in
           progtrace.scope_seconds(p, [first]).items()}
     # the loop's own 1 ms (16 less its children's 15) is bare `layers`
@@ -239,3 +248,101 @@ def test_the_wire_decoder_reads_each_ops_tf_op():
         [o[:2] for o in planes[0]["ops"]]
     scopes = [o.scope for o in progtrace.reduce([], planes).ops]
     assert scopes == ["kv_write", "mlp", None]
+
+
+# -- several devices, and the parent's readings -------------------------------
+
+def read_all(p: progtrace.Profile, trace: devtrace.Reduced) -> dict:
+    """Every reading of a profile and its trace that the harness takes."""
+    from bench.readings import reader
+    from bench.spec import model_config, family
+
+    r = reading(p)
+    r.trace = trace
+    with open(BENCH / "configs" / "qwen3-8b-l12.json") as f:
+        config = json.load(f)
+    r.cfg, r.family = model_config(config), family(config)
+    r.peaks = {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9}
+    first = [m for m in trace.modules if round(m.start, 9) == 0.018]
+    out = {"busy_s": devtrace.busy_seconds(trace),
+           "idle_gaps": devtrace.idle_gaps(trace),
+           "calls": [[h.name, h.start, h.end, d.start, d.end]
+                     for h, d in devtrace.calls(trace, "decode_step")],
+           "top_ops": devtrace.top_ops(trace),
+           "top_gaps": devtrace.top_gaps(trace),
+           "scope_seconds": {str(k): v for k, v in
+                             progtrace.scope_seconds(p, first).items()},
+           "ticks": [[x.start, x.end]
+                     for x in progtrace.ticks(p, trace.window)],
+           "tick_idle": progtrace.tick_idle(p, trace),
+           "idle_by_span": progtrace.idle_by_span(p, trace)}
+    for m in ("tick_idle_ms.chat", "kv_write_ms.chat", "attention_ms.long",
+              "moe_ms.chat", "decode_step_ms.chat", "prefill_chunk_ms.chat",
+              "device_idle_pct.long", "step_mfu.long",
+              "decode_step_roofline.long"):
+        out[m] = reader(m)(r)
+    return json.loads(json.dumps(out))      # tuples as lists
+
+
+def test_readings_match_the_parent_tree():
+    golden = json.loads((BENCH / "tests" / "data" / "parity.json")
+                        .read_text())["trace"]["progtrace"]
+    assert read_all(profile(), harness_trace()) == golden
+
+
+def four_devices(trim_last: float = 0.0, shift_last: float = 0.0):
+    """``profile()`` and ``harness_trace()`` on four devices; TPU:3's last
+    decode call ends ``trim_last`` ms early, or runs ``shift_last`` ms
+    late."""
+    planes = harness_planes(4)
+    ops = [[list(o) for o in OPS] for _ in range(4)]
+    for ln in planes[4].lines:
+        ln.events[-1].duration_ns -= int(trim_last * MS)
+        ln.events[-1].start_ns += int(shift_last * MS)
+    ops[3][-1][1] -= trim_last * MS
+    for o in ops[3][-2:]:
+        o[0] += shift_last * MS
+    p = progtrace.reduce(copy.deepcopy(SPANS), [
+        {"name": f"/device:TPU:{i}", "ops": [tuple(o) for o in ops[i]]}
+        for i in range(4)])
+    return p, devtrace.reduce_planes(planes)
+
+
+def test_four_identical_devices_read_as_one():
+    one, many = read_all(profile(), harness_trace()), read_all(*four_devices())
+    for k in ("idle_gaps", "calls", "ticks"):
+        assert many[k] == one[k], k
+    for k in ("busy_s", "tick_idle", "tick_idle_ms.chat", "kv_write_ms.chat",
+              "attention_ms.long", "moe_ms.chat", "decode_step_ms.chat",
+              "device_idle_pct.long"):
+        assert many[k] == pytest.approx(one[k]), k
+    assert many["idle_by_span"] == pytest.approx(one["idle_by_span"])
+    assert many["scope_seconds"] == pytest.approx(one["scope_seconds"])
+    assert many["step_mfu.long"] == pytest.approx(one["step_mfu.long"] / 4)
+
+
+def test_an_idle_device_shows_in_the_averaged_tick_idle():
+    # TPU:3's second decode call ends at 80 ms, not 88: 8 ms more idle in
+    # the tick of 60..90 on that device, its kv_write 8 ms shorter
+    one = read_all(profile(), harness_trace())
+    got = read_all(*four_devices(trim_last=8.0))
+    assert got["tick_idle"] == pytest.approx([0.010, 0.0045 + 0.008 / 4])
+    assert got["idle_by_span"]["serve.retire"] == pytest.approx(
+        one["idle_by_span"]["serve.retire"])
+    assert sum(got["idle_by_span"].values()) == pytest.approx(
+        sum(one["idle_by_span"].values()) + 0.008 / 4)
+    # the call still runs to 88 ms, where the other devices end it
+    assert got["calls"] == one["calls"]
+    assert got["kv_write_ms.chat"] == pytest.approx((4 + (3 * 10 + 2) / 4)
+                                                    / 2)
+
+
+def test_a_device_that_starts_late_stretches_the_call():
+    # TPU:3 runs the second decode call 62.5..88 two ms late, to 90 ms
+    one = read_all(profile(), harness_trace())
+    got = read_all(*four_devices(shift_last=2.0))
+    assert [round(c[4] * 1e3, 6) for c in got["calls"]] == [38, 50, 90]
+    assert got["decode_step_ms.chat"] == pytest.approx(
+        one["decode_step_ms.chat"] + 1.0)
+    # its ops move with it: the same time by scope
+    assert got["kv_write_ms.chat"] == pytest.approx(one["kv_write_ms.chat"])
